@@ -6,7 +6,7 @@ attention softmax, FxP8-quantized KV cache).
 
     PYTHONPATH=src python examples/serve_lm.py
     PYTHONPATH=src python examples/serve_lm.py --arch mamba2_370m --gen 32
-    PYTHONPATH=src python examples/serve_lm.py --backend pallas --no-overlap
+    PYTHONPATH=src python examples/serve_lm.py --backend auto --no-overlap
 """
 import argparse
 

@@ -17,6 +17,10 @@ contract:
   * ``auto``             — resolves to ``pallas`` on TPU, else
                            ``pallas-interpret``.
 
+``pallas`` means the compiled kernels and nothing else: asked for where
+JAX sees no TPU, it raises instead of quietly running interpret mode, so
+a chip run that lost its chip fails rather than finishing on the CPU.
+
 Selection has two inputs, in priority order:
 
   1. a dynamic ``with backend("pallas"):`` override (trace-time scoped), and
@@ -66,17 +70,22 @@ def current_override() -> Optional[str]:
 def resolve(policy_backend: Optional[str]) -> str:
     """Collapse (dynamic override, policy field) to a concrete backend name.
 
-    'auto' picks the compiled kernels on TPU and interpret mode elsewhere;
-    'pallas' likewise degrades to 'pallas-interpret' off-TPU (Mosaic can't
-    compile for CPU — interpret mode is the same kernels, validated)."""
+    'auto' picks the compiled kernels on TPU and interpret mode elsewhere.
+    'pallas' off-TPU is an error: Mosaic compiles for the TPU only, and
+    interpret mode is asked for by name ('pallas-interpret')."""
     name = current_override() or policy_backend or "reference"
     if name not in BACKENDS:
         raise ValueError(
             f"unknown backend {name!r}; expected one of {BACKENDS}")
+    on_tpu = jax.default_backend() == "tpu"
     if name == "auto":
-        name = "pallas"
-    if name == "pallas" and jax.default_backend() != "tpu":
-        return "pallas-interpret"
+        return "pallas" if on_tpu else "pallas-interpret"
+    if name == "pallas" and not on_tpu:
+        raise RuntimeError(
+            "backend 'pallas' runs the compiled Mosaic kernels, but JAX "
+            f"found no TPU (default backend: {jax.default_backend()!r}); "
+            "use 'pallas-interpret' to run the same kernels in interpret "
+            "mode")
     return name
 
 

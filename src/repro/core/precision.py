@@ -43,7 +43,15 @@ from .tiers import TIER_LADDER, TIERS, PrecisionTier, tier_index
 
 __all__ = ["PrecisionPolicy", "qmatmul", "qeinsum", "PrecisionTier",
            "TIERS", "TIER_LADDER", "tier_index", "tier_policy",
-           "policy_tier"]
+           "policy_tier", "ATTN_PRECISION"]
+
+#: Precision of the float attention dots (scores, and the softmax weights
+#: against V) in the XLA paths and the fused paged kernel alike. At the
+#: default precision XLA and Mosaic both round f32 operands to bf16 on a
+#: TPU, so the softmax weights lose 2^-9; pinned to HIGHEST, the kernel's
+#: dot and XLA's give the same bits on a v5e, and the weights keep their
+#: f32 value (on the CPU both are plain f32 anyway).
+ATTN_PRECISION = jax.lax.Precision.HIGHEST
 
 
 def _dispatch():

@@ -192,7 +192,6 @@ class MeshRules:
         quantizer). Falls back to a plain constraint when S doesn't divide
         the model axis (decode)."""
         from ..core.fxp import FORMATS, dequantize, quantize
-        from jax.experimental.shard_map import shard_map
 
         if x.ndim != 3 or x.shape[1] % self.mesh.shape["model"] != 0:
             return self.constraint(x, None, None)
@@ -213,11 +212,11 @@ class MeshRules:
                 sc = jax.lax.all_gather(sc, "model", axis=1, tiled=True)
                 return c, sc
 
-            c2, s2 = shard_map(
+            c2, s2 = jax.shard_map(
                 g, mesh=mesh,
                 in_specs=(P(dps, "model", None), P(dps, "model", None)),
                 out_specs=(P(dps, None, None), P(dps, None, None)),
-                check_rep=False)(codes, scale)
+                check_vma=False)(codes, scale)
             return dequantize(c2, s2, xx.dtype)
 
         def cg_fwd(xx):
@@ -228,10 +227,10 @@ class MeshRules:
                 return jax.lax.psum_scatter(gl, "model",
                                             scatter_dimension=1, tiled=True)
 
-            gx = shard_map(r, mesh=mesh,
-                           in_specs=(P(dps, None, None),),
-                           out_specs=P(dps, "model", None),
-                           check_rep=False)(gy.astype(jnp.float32))
+            gx = jax.shard_map(r, mesh=mesh,
+                               in_specs=(P(dps, None, None),),
+                               out_specs=P(dps, "model", None),
+                               check_vma=False)(gy.astype(jnp.float32))
             return (gx.astype(gy.dtype),)
 
         cg.defvjp(cg_fwd, cg_bwd)

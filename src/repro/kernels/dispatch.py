@@ -18,13 +18,20 @@ fly, reference-identical per-tensor scales) or as `QuantizedTensor`
 (quantize-once storage: int codes + per-channel scale, FxP4 nibble-packed —
 the codes are what moves HBM→VMEM). Dequant and the optional Flex-PE AF are
 fused into the GEMM epilogue: MAC→AF is one kernel launch.
+
+Under a multi-device mesh (JAX's abstract-mesh context, which the model
+entry points enter from their `shard` argument) every Pallas call runs
+inside `jax.shard_map` on replicated operands, because Mosaic kernels
+cannot be partitioned automatically (see `_replicated`).
 """
 from __future__ import annotations
 
+import functools
 from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from ..core.activation import default_stages, flex_af
 from ..core.fxp import FORMATS, fake_quant, quantize
@@ -69,6 +76,24 @@ def lookup(op: str, backend: str) -> tuple[Callable, bool]:
 
 def supports_fused_af(af: Optional[str]) -> bool:
     return af is None or af in PALLAS_AFS
+
+
+def _replicated(kernel, *args):
+    """Call a Pallas kernel on replicated operands. Under a multi-device
+    mesh Mosaic calls cannot be partitioned automatically, so the call
+    runs inside `jax.shard_map` with every operand and result
+    replicated: the partitioner all-gathers every model-sharded weight
+    in front of it, each device computes the whole result, and tp>1
+    stays bit-identical to one device. So `--tp N` on the pallas path
+    moves every weight over the interconnect each step and does N times
+    the kernel work, with no speed-up (ROADMAP Queue 1). The KV pool is
+    not gathered: where tp partitions its block axis, attention skips
+    the fused kernel for the gather path (`layers.attention`)."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or mesh.size == 1:
+        return kernel(*args)
+    return jax.shard_map(kernel, mesh=mesh, in_specs=P(), out_specs=P(),
+                         check_vma=False)(*args)
 
 
 # ---------------------------------------------------------------------------
@@ -188,10 +213,11 @@ def _matmul_pallas(x, w, policy, af=None, interpret=False):
     scale = jnp.broadcast_to((sx * wscale).astype(jnp.float32), (1, n))
 
     # pad to MXU-aligned blocks (zero codes contribute nothing to the dot;
-    # padded scale columns are sliced away below)
+    # padded scale columns are sliced away below); a packed block holds
+    # bn // 2 bytes, so packed tiles are 256 columns to fill 128 lanes
     bm = min(128, round_up(max(m, 1), 8))
     bk = 128
-    bn = 128
+    bn = 256 if packed else 128
     xc = pad_to(pad_to(xc, bm, 0), bk, 1)
     wb = pad_to(wb, bk, 0)
     if packed:
@@ -203,10 +229,10 @@ def _matmul_pallas(x, w, policy, af=None, interpret=False):
     scale = pad_to(scale, n_k, 1, value=1.0)
 
     hr, lv = default_stages(policy.af if policy is not None else None)
-    out = fxp_gemm_fused_pallas(
-        xc, wb, scale, packed=packed, af=af if fuse_af else None,
+    out = _replicated(functools.partial(
+        fxp_gemm_fused_pallas, packed=packed, af=af if fuse_af else None,
         hr_stages=hr, lv_stages=lv, blocks=(bm, bn, bk),
-        interpret=interpret)
+        interpret=interpret), xc, wb, scale)
     out = out[:m, :n]
     if fuse_af:
         # write-back quantization of the AF result (the kernel's epilogue
@@ -237,7 +263,8 @@ def _act_pallas(x, af, policy, interpret=False):
     if af not in PALLAS_AFS:
         return _act_reference(x, af, policy)
     precision = policy.af if policy is not None else None
-    return cordic_af(x, af, precision=precision, interpret=interpret)
+    return _replicated(functools.partial(
+        cordic_af, af=af, precision=precision, interpret=interpret), x)
 
 
 @register("softmax", "reference")
@@ -252,7 +279,8 @@ def _softmax_pallas(x, policy, axis=-1, interpret=False):
     if axis not in (-1, x.ndim - 1):
         return _softmax_reference(x, policy, axis=axis)
     precision = policy.af if policy is not None else None
-    return cordic_softmax(x, precision=precision, interpret=interpret)
+    return _replicated(functools.partial(
+        cordic_softmax, precision=precision, interpret=interpret), x)
 
 
 # ---------------------------------------------------------------------------
@@ -290,11 +318,15 @@ def _paged_attention_pallas(q, k_pool, v_pool, k_scale, v_scale,
             q, k_pool, v_pool, k_scale, v_scale, block_tables, policy,
             lengths=lengths, kv_valid=kv_valid, positions=positions,
             fmt=fmt, int_attention=int_attention)
-    return _paged_attn_pallas(q, k_pool, v_pool, k_scale, v_scale,
-                              block_tables, lengths=lengths,
-                              kv_valid=kv_valid, positions=positions,
-                              fmt=fmt, int_attention=int_attention,
-                              policy=policy, interpret=interpret)
+    def kernel(q, k_pool, v_pool, k_scale, v_scale, block_tables, lengths,
+               kv_valid, positions):
+        return _paged_attn_pallas(q, k_pool, v_pool, k_scale, v_scale,
+                                  block_tables, lengths=lengths,
+                                  kv_valid=kv_valid, positions=positions,
+                                  fmt=fmt, int_attention=int_attention,
+                                  policy=policy, interpret=interpret)
+    return _replicated(kernel, q, k_pool, v_pool, k_scale, v_scale,
+                       block_tables, lengths, kv_valid, positions)
 
 
 # ---------------------------------------------------------------------------

@@ -5,13 +5,15 @@ int32 accumulation (the hardware's FxP32 accumulator), MXU-aligned
 128x128x128 default blocks, and an optional packed-int4 operand path where
 two FxP4 codes share one int8 byte — the SIMD storage win: int4 weights move
 half the HBM->VMEM bytes and unpack with shift/mask inside the kernel,
-mirroring the PE's lane-split barrel shifter.
+mirroring the PE's lane-split barrel shifter. A packed weight block is
+(bk, bn // 2) bytes, so on the chip bn is a multiple of 256 (a 128-lane
+byte block); int8 code blocks enter the MXU as int8.
 
 Grid is (M/bm, N/bn, K/bk) with K innermost; accumulation is
 output-stationary across K steps. Two kernel families:
 
-  * code kernels (`fxp_gemm_pallas`, `fxp4_gemm_packed_pallas`) — int32
-    output of raw code dots, bit-identical to the ref oracle.
+  * code kernel (`fxp_gemm_pallas`) — int32 output of raw code dots,
+    bit-identical to the ref oracle.
   * fused kernel (`fxp_gemm_fused_pallas`) — int32 VMEM scratch accumulator
     with a dequant (+ optional CORDIC AF) epilogue at the last K step, so
     the PE's MAC→AF pipeline is ONE kernel launch: f32 output =
@@ -48,16 +50,46 @@ DEFAULT_BLOCKS = (128, 128, 128)
 FUSED_AFS = ("relu", "sigmoid", "tanh", "silu", "gelu", "exp")
 
 
-def _unpack_nibbles(wp: jax.Array) -> jax.Array:
-    """packed int8 bytes [bk, bn//2] -> int32 codes [bk, bn]: low nibble =
-    even element, high nibble = odd (lane order of core.simd.pack)."""
-    wp = wp.astype(jnp.int32)
-    lo = wp & 0xF
-    lo = jnp.where(lo >= 8, lo - 16, lo)        # sign-extend nibble
-    hi = (wp >> 4) & 0xF
-    hi = jnp.where(hi >= 8, hi - 16, hi)
-    bk, bn2 = wp.shape
-    return jnp.stack([lo, hi], axis=-1).reshape(bk, bn2 * 2)
+def _code_dot(x, w):
+    """Exact int32 dot of two integer code blocks. int8 codes go into the
+    MXU as int8 (its integer operand type; Mosaic refuses int32 operands);
+    wider codes widen to int32 first (the >8-bit contract below)."""
+    if x.dtype == jnp.int8 and w.dtype == jnp.int8:
+        return jnp.dot(x, w, preferred_element_type=jnp.int32)
+    return jnp.dot(x.astype(jnp.int32), w.astype(jnp.int32),
+                   preferred_element_type=jnp.int32)
+
+
+def _acc_dot(x, w, acc_dtype):
+    if acc_dtype == jnp.int32:
+        return _code_dot(x, w)
+    return jnp.dot(x.astype(acc_dtype), w.astype(acc_dtype),
+                   preferred_element_type=acc_dtype)
+
+
+def _nibbles(wp):
+    """packed int8 bytes [bk, h] -> sign-extended int8 codes (lo, hi), each
+    [bk, h]: the even and odd element of every byte (lane order of
+    core.simd.pack). Shift pairs on the widened byte sign-extend each
+    nibble; no lane interleave is needed because the kernel keeps the two
+    halves apart (see `_split_pairs`)."""
+    w = wp.astype(jnp.int32)
+    lo = jnp.left_shift(w, 28) >> 28
+    hi = jnp.left_shift(w, 24) >> 28
+    return lo.astype(jnp.int8), hi.astype(jnp.int8)
+
+
+def _split_pairs(a, bn):
+    """[r, n] logical columns -> the packed kernel's column order: within
+    each bn-wide tile, the even columns, then the odd ones."""
+    r, n = a.shape
+    return a.reshape(r, n // bn, bn // 2, 2).swapaxes(-1, -2).reshape(r, n)
+
+
+def _merge_pairs(a, bn):
+    """Inverse of `_split_pairs`."""
+    r, n = a.shape
+    return a.reshape(r, n // bn, 2, bn // 2).swapaxes(-1, -2).reshape(r, n)
 
 
 def _gemm_kernel(x_ref, w_ref, o_ref):
@@ -67,38 +99,30 @@ def _gemm_kernel(x_ref, w_ref, o_ref):
     def _():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    o_ref[...] += jnp.dot(x_ref[...].astype(jnp.int32),
-                          w_ref[...].astype(jnp.int32),
-                          preferred_element_type=jnp.int32)
-
-
-def _gemm_kernel_packed4(x_ref, wp_ref, o_ref):
-    """w block arrives as packed int8 bytes: low nibble = even col-pair
-    element, high nibble = odd (lane order of core.simd.pack)."""
-    k = pl.program_id(2)
-
-    @pl.when(k == 0)
-    def _():
-        o_ref[...] = jnp.zeros_like(o_ref)
-
-    w = _unpack_nibbles(wp_ref[...])
-    o_ref[...] += jnp.dot(x_ref[...].astype(jnp.int32), w,
-                          preferred_element_type=jnp.int32)
+    o_ref[...] += _code_dot(x_ref[...], w_ref[...])
 
 
 def _gemm_kernel_fused(x_ref, w_ref, s_ref, o_ref, acc_ref, *, nk, packed,
                        af, hr, lv):
-    """Output-stationary code GEMM with dequant(+AF) epilogue at k == nk-1."""
+    """Output-stationary code GEMM with dequant(+AF) epilogue at k == nk-1.
+    Packed weights accumulate as two lane-aligned halves: the low nibbles
+    (even columns) into the first half of the tile, the high nibbles (odd
+    columns) into the second."""
     k = pl.program_id(2)
 
     @pl.when(k == 0)
     def _():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    w = _unpack_nibbles(w_ref[...]) if packed else w_ref[...]
+    x = x_ref[...]
     acc_t = acc_ref.dtype
-    acc_ref[...] += jnp.dot(x_ref[...].astype(acc_t), w.astype(acc_t),
-                            preferred_element_type=acc_t)
+    if packed:
+        lo, hi = _nibbles(w_ref[...])
+        h = lo.shape[1]
+        acc_ref[:, :h] += _acc_dot(x, lo, acc_t)
+        acc_ref[:, h:] += _acc_dot(x, hi, acc_t)
+    else:
+        acc_ref[...] += _acc_dot(x, w_ref[...], acc_t)
 
     @pl.when(k == nk - 1)
     def _():
@@ -133,26 +157,6 @@ def fxp_gemm_pallas(x_codes: jax.Array, w_codes: jax.Array,
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.int32),
         interpret=interpret,
     )(x_codes, w_codes)
-
-
-def fxp4_gemm_packed_pallas(x_codes: jax.Array, w_packed: jax.Array,
-                            blocks=DEFAULT_BLOCKS, interpret: bool = False):
-    """int8[M,K] (FxP4 codes) @ packed-nibble int8[K, N//2] -> int32[M,N]."""
-    m, k = x_codes.shape
-    k2, n2 = w_packed.shape
-    assert k == k2
-    n = n2 * 2
-    bm, bn, bk = (min(b, d) for b, d in zip(blocks, (m, n, k)))
-    assert m % bm == 0 and n % bn == 0 and k % bk == 0 and bn % 2 == 0
-    return pl.pallas_call(
-        _gemm_kernel_packed4,
-        grid=(m // bm, n // bn, k // bk),
-        in_specs=[pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
-                  pl.BlockSpec((bk, bn // 2), lambda i, j, kk: (kk, j))],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((m, n), jnp.int32),
-        interpret=interpret,
-    )(x_codes.astype(jnp.int8), w_packed.astype(jnp.int8))
 
 
 def fxp_gemm_fused_pallas(x_codes: jax.Array, w_codes: jax.Array,
@@ -196,7 +200,10 @@ def fxp_gemm_fused_pallas(x_codes: jax.Array, w_codes: jax.Array,
                              af=af, hr=hr_stages, lv=lv_stages)
     w_spec = (pl.BlockSpec((bk, bn // 2), lambda i, j, kk: (kk, j)) if packed
               else pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j)))
-    return pl.pallas_call(
+    scale = scale.astype(jnp.float32)
+    if packed:
+        scale = _split_pairs(scale, bn)
+    out = pl.pallas_call(
         kern,
         grid=(m // bm, n // bn, nk),
         in_specs=[pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
@@ -206,4 +213,5 @@ def fxp_gemm_fused_pallas(x_codes: jax.Array, w_codes: jax.Array,
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, bn), acc_dtype)],
         interpret=interpret,
-    )(x_codes, w_codes, scale.astype(jnp.float32))
+    )(x_codes, w_codes, scale)
+    return _merge_pairs(out, bn) if packed else out

@@ -64,8 +64,9 @@ def fxp_gemm(x: jax.Array, w: jax.Array, precision: str = "fxp8",
     wc, sw = quantize(w, fmt)
     # pad to MXU-aligned blocks (zeros contribute nothing to the dot)
     bm = min(128, round_up(max(m, 1), 8))
+    bn = 256 if packed else 128      # a packed tile is bn // 2 byte lanes
     xcp = pad_to(pad_to(xc, bm, 0), 128, 1)
-    wcp = pad_to(pad_to(wc, 128, 0), 128, 1)
+    wcp = pad_to(pad_to(wc, 128, 0), bn, 1)
 
     if packed:
         lo = wcp.astype(jnp.int8)[:, 0::2] & 0xF
@@ -77,7 +78,7 @@ def fxp_gemm(x: jax.Array, w: jax.Array, precision: str = "fxp8",
     hr, lv, _ = PARETO_STAGES[fmt.bits]
     out = fxp_gemm_fused_pallas(xcp, wcp, scale, packed=packed, af=af,
                                 hr_stages=hr, lv_stages=lv,
-                                blocks=(bm, 128, 128),
+                                blocks=(bm, bn, 128),
                                 wide_exact=wide_exact, interpret=interpret)
     out = out[:m, :n]
     if af is not None:

@@ -17,8 +17,12 @@ contraction run once in the epilogue over the full staged row. Keeping
 the reductions full-row (rather than rescaling partial accumulators
 block-by-block) is what makes the kernel BIT-EXACT against the gathered
 reference path — fp addition is not associative, so a true streaming
-accumulator would round differently. On a real-TPU Mosaic build the
-scratch bound (MB*bs rows of VMEM) is the lever to revisit; see ROADMAP.
+accumulator would round differently. Scores stage as [MB, KV, g, bs]:
+every block writes one leading-axis slot (a lane-dim write at offset
+j*bs is refused by Mosaic unless bs is a multiple of 128), and the
+epilogue concatenates the slots back into the [KV, g, S] row. The staged
+row lives in VMEM, so the scratch (about 20 KiB per position at
+MiniCPM-2B's 36 x 64 KV heads) bounds the context length; see ROADMAP.
 
 Masking is in-kernel: position p = j*bs + offset is valid iff p <= the
 row's query position and p < its valid length; unallocated table entries
@@ -43,9 +47,19 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ...core.fxp import quantize
+from ...core.precision import ATTN_PRECISION
 
 #: meta rows: per-slot (lengths, kv_valid_len, query position) int32
 META_COLS = 3
+
+#: scoped-VMEM request for the walk: the staged row outgrows Mosaic's
+#: 16 MiB default past a few hundred positions; v5e has 128 MiB of VMEM
+VMEM_LIMIT_BYTES = 100 * 2**20
+
+
+def _row(s_scr, mb):
+    """[MB, KV, g, bs] staged score slots -> the [KV, g, MB*bs] row."""
+    return jnp.concatenate([s_scr[i] for i in range(mb)], axis=-1)
 
 
 def _block_positions(j, bs):
@@ -72,7 +86,8 @@ def _float_body(tbl_ref, meta_ref, q_ref, o_ref, s_scr, v_scr, m_scr, *,
 
     scale = 1.0 / (hd ** 0.5)
     qf = q_ref[0].astype(jnp.float32)                       # [KV, g, hd]
-    s_blk = jnp.einsum("kgd,skd->kgs", qf, kf) * scale      # [KV, g, bs]
+    s_blk = jnp.einsum("kgd,skd->kgs", qf, kf,
+                       precision=ATTN_PRECISION) * scale    # [KV, g, bs]
 
     pos = _block_positions(j, bs)
     qpos = meta_ref[b, 2]
@@ -81,15 +96,16 @@ def _float_body(tbl_ref, meta_ref, q_ref, o_ref, s_scr, v_scr, m_scr, *,
     s_blk = jnp.where((pos < kvv)[None, None, :], s_blk, -1e30)
 
     m_scr[...] = jnp.maximum(m_scr[...], jnp.max(s_blk, axis=-1))
-    s_scr[:, :, pl.ds(j * bs, bs)] = s_blk
+    s_scr[j] = s_blk
     v_scr[pl.ds(j * bs, bs)] = vf
 
     @pl.when(j == mb - 1)
     def _():
-        s_all = s_scr[...]                                  # [KV, g, S]
+        s_all = _row(s_scr, mb)                             # [KV, g, S]
         p = exp_fn(s_all - m_scr[...][..., None])
         denom = jnp.sum(p, axis=-1)                         # [KV, g]
-        o = jnp.einsum("kgs,skd->kgd", p, v_scr[...])       # [KV, g, hd]
+        o = jnp.einsum("kgs,skd->kgd", p, v_scr[...],
+                       precision=ATTN_PRECISION)            # [KV, g, hd]
         out = div_fn(o, denom[..., None])
         o_ref[0] = out.reshape(kvh * g, hd).astype(o_ref.dtype)
 
@@ -125,20 +141,21 @@ def _int_kernel(tbl_ref, meta_ref, qc_ref, sq_ref, k_ref, v_ref, ks_ref,
     b, j = pl.program_id(0), pl.program_id(1)
 
     alloc = tbl_ref[b, j] < nb
-    kc = jnp.where(alloc, k_ref[0].astype(jnp.int32), 0)    # [bs, KV, hd]
-    vc = jnp.where(alloc, v_ref[0].astype(jnp.int32), 0)
+    kc = jnp.where(alloc, k_ref[0], jnp.zeros((), k_ref.dtype))  # [bs,KV,hd]
+    vc = jnp.where(alloc, v_ref[0], jnp.zeros((), v_ref.dtype))
     ks = jnp.where(alloc, ks_ref[0][..., 0], 0.0)           # [bs, KV]
     vs = jnp.where(alloc, vs_ref[0][..., 0], 0.0)
 
-    qc = qc_ref[0].astype(jnp.int32)                        # [KV, g, hd]
-    s_scr[:, :, pl.ds(j * bs, bs)] = jnp.einsum("kgd,skd->kgs", qc, kc)
+    # int8 codes go into the MXU as int8, accumulating exactly in int32
+    s_scr[j] = jnp.einsum("kgd,skd->kgs", qc_ref[0], kc,
+                          preferred_element_type=jnp.int32)
     ks_scr[pl.ds(j * bs, bs)] = ks
     vs_scr[pl.ds(j * bs, bs)] = vs
     v_scr[pl.ds(j * bs, bs)] = vc
 
     @pl.when(j == mb - 1)
     def _():
-        s = s_scr[...].astype(jnp.float32) * sq_ref[0]      # [KV, g, S]
+        s = _row(s_scr, mb).astype(jnp.float32) * sq_ref[0]  # [KV, g, S]
         s = s * ks_scr[...].T[:, None, :]
         pos = _block_positions(0, mb * bs)
         mask = (pos <= meta_ref[b, 2]) & (pos < meta_ref[b, 1])
@@ -146,7 +163,8 @@ def _int_kernel(tbl_ref, meta_ref, qc_ref, sq_ref, k_ref, v_ref, ks_ref,
         p = softmax_fn(s)
         pv = p.astype(jnp.float32) * vs_scr[...].T[:, None, :]
         pvc, spv = quantize(pv, fmt, axis=-1)
-        o = jnp.einsum("kgs,skd->kgd", pvc.astype(jnp.int32), v_scr[...])
+        o = jnp.einsum("kgs,skd->kgd", pvc, v_scr[...],
+                       preferred_element_type=jnp.int32)
         out = o.astype(jnp.float32) * spv
         o_ref[0] = out.reshape(kvh * g, hd).astype(o_ref.dtype)
 
@@ -185,7 +203,7 @@ def paged_attention_float_pallas(q, k_pool, v_pool, block_tables, meta, *,
               exp_fn=exp_fn, div_fn=div_fn)
     q_spec = pl.BlockSpec((1, kvh, g, hd),
                           lambda bb, j, tbl, meta: (bb, 0, 0, 0))
-    scratch = [pltpu.VMEM((kvh, g, s), jnp.float32),
+    scratch = [pltpu.VMEM((mb, kvh, g, bs), jnp.float32),
                pltpu.VMEM((s, kvh, hd), jnp.float32),
                pltpu.VMEM((kvh, g), jnp.float32)]
     quant = k_scale is not None
@@ -201,6 +219,8 @@ def paged_attention_float_pallas(q, k_pool, v_pool, block_tables, meta, *,
     out = pl.pallas_call(
         kern, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, hd), out_dtype),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret)(*args)
     return out
 
@@ -225,14 +245,16 @@ def paged_attention_int_pallas(q_codes, q_scale, k_pool, v_pool, k_scale,
                          lambda bb, j, tbl, meta: (bb, 0, 0, 0))]
     pool_specs = [(1, bs, kvh, hd), (1, bs, kvh, hd),
                   (1, bs, kvh, 1), (1, bs, kvh, 1)]
-    scratch = [pltpu.VMEM((kvh, g, s), jnp.int32),
+    scratch = [pltpu.VMEM((mb, kvh, g, bs), jnp.int32),
                pltpu.VMEM((s, kvh), jnp.float32),
-               pltpu.VMEM((s, kvh, hd), jnp.int32),
+               pltpu.VMEM((s, kvh, hd), k_pool.dtype),
                pltpu.VMEM((s, kvh), jnp.float32)]
     grid_spec = _grid_spec(b, mb, nb, pool_specs, lead, scratch, h, hd)
     out = pl.pallas_call(
         kern, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, hd), out_dtype),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret)(
             block_tables, meta, q_codes, q_scale.astype(jnp.float32),
             k_pool, v_pool, k_scale.astype(jnp.float32),

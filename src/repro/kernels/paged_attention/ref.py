@@ -27,6 +27,7 @@ import jax.numpy as jnp
 from ...core import cordic
 from ...core.activation import default_stages, softmax_lv_stages
 from ...core.fxp import dequantize, quantize
+from ...core.precision import ATTN_PRECISION
 
 
 def gather_pool_view(pool, block_tables):
@@ -69,7 +70,7 @@ def _float_decode(q, k, v, lengths, kv_valid, policy):
 
     qh = q.reshape(b, sq, kvh, g, hd)
     s = jnp.einsum("bqkgd,bskd->bqkgs", qh.astype(jnp.float32),
-                   k.astype(jnp.float32)) * scale
+                   k.astype(jnp.float32), precision=ATTN_PRECISION) * scale
     s = s.reshape(b, sq, h, skv)
     qpos = qoff[:, None] + jnp.arange(sq)[None, :]
     mask = kv_pos[None, None, :] <= qpos[:, :, None]
@@ -80,7 +81,8 @@ def _float_decode(q, k, v, lengths, kv_valid, policy):
     p = expf(s - m)
     denom = jnp.sum(p, axis=-1)
     ph = p.reshape(b, sq, kvh, g, skv)
-    o = jnp.einsum("bqkgs,bskd->bqkgd", ph, v.astype(jnp.float32))
+    o = jnp.einsum("bqkgs,bskd->bqkgd", ph, v.astype(jnp.float32),
+                   precision=ATTN_PRECISION)
     o = o.reshape(b, sq, h, hd)
     return _final_div(o, denom[..., None], skv, policy).astype(q.dtype)
 

@@ -27,6 +27,7 @@ from ..configs.base import ARCH_IDS, SHAPES, arch_shapes, get_config
 from ..core.precision import PrecisionPolicy
 from . import steps as S
 from .mesh import make_production_mesh
+from .roofline import DRYRUN_DEVICE_KIND
 
 _DTYPE_BYTES = {"f64": 8, "f32": 4, "bf16": 2, "f16": 2, "s64": 8, "u64": 8,
                 "s32": 4, "u32": 4, "s16": 2, "u16": 2, "s8": 1, "u8": 1,
@@ -209,6 +210,7 @@ def dryrun_cell(arch: str, shape: str, *, multi_pod: bool = False,
 
     return dict(
         cell, status="ok", n_chips=n_chips,
+        device_kind=DRYRUN_DEVICE_KIND,
         lower_s=round(t_lower, 2), compile_s=round(t_compile, 2),
         flops_per_device=flops_cal,
         bytes_per_device=bytes_cal,

@@ -9,41 +9,10 @@ bandwidth lives — the standard >=2-pod layout.
 """
 from __future__ import annotations
 
-import enum
-import functools
-import inspect
 import math
 
 import jax
 from jax.sharding import Mesh
-
-
-def _install_axis_type_compat() -> None:
-    """Version-guarded fallback for JAX < 0.5: `jax.sharding.AxisType` and
-    the `jax.make_mesh(..., axis_types=...)` kwarg don't exist in 0.4.x.
-    Install a no-op stand-in so explicit-sharding-typed call sites (here and
-    in tests) degrade to plain auto meshes — semantically identical, since
-    Auto is the 0.4.x default behaviour."""
-    if hasattr(jax.sharding, "AxisType"):
-        return
-
-    class AxisType(enum.Enum):
-        Auto = "auto"
-        Explicit = "explicit"
-        Manual = "manual"
-
-    jax.sharding.AxisType = AxisType
-    orig = jax.make_mesh
-    if "axis_types" not in inspect.signature(orig).parameters:
-        @functools.wraps(orig)
-        def make_mesh(*args, axis_types=None, **kwargs):
-            del axis_types  # 0.4.x meshes are always Auto
-            return orig(*args, **kwargs)
-
-        jax.make_mesh = make_mesh
-
-
-_install_axis_type_compat()
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
@@ -69,11 +38,17 @@ def make_host_mesh(model_parallel: int = 1) -> Mesh:
                          axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 
-def make_tp_mesh(tp: int = 1) -> Mesh:
-    """Serving tensor-parallel mesh: (data=1, model=tp) over the first
-    `tp` devices. Unlike `make_host_mesh` this never silently degrades —
-    asking for more model parallelism than there are devices is a
-    configuration error, not a preference."""
+def make_tp_mesh(tp: int = 1, replica: int = 0) -> Mesh:
+    """Serving tensor-parallel mesh: (data=1, model=tp) over replica
+    `replica`'s own `tp` devices, devices [replica*tp, (replica+1)*tp).
+    Unlike `make_host_mesh` this never degrades the degree — asking for
+    more model parallelism than there are devices is a configuration
+    error, not a preference. So is a fleet larger than the host's chips:
+    a replica past the last whole group of devices is refused rather
+    than stacked on chips another replica already holds. Only CPU
+    devices, which are host threads rather than chips, may be shared:
+    there the index wraps round onto groups already in use, so tests
+    can build fleets larger than the host."""
     devices = jax.devices()
     if tp < 1:
         raise ValueError(f"tp must be >= 1, got {tp}")
@@ -83,6 +58,14 @@ def make_tp_mesh(tp: int = 1) -> Mesh:
             f"{len(devices)} — on CPU set XLA_FLAGS="
             f"--xla_force_host_platform_device_count={tp} before importing "
             "jax")
+    groups = len(devices) // tp
+    if replica >= groups and devices[0].platform != "cpu":
+        raise RuntimeError(
+            f"replica {replica} at tp={tp} needs devices "
+            f"[{replica * tp}, {(replica + 1) * tp}), but the host has "
+            f"{len(devices)} {devices[0].platform} devices ({groups} "
+            f"replica(s) of tp={tp}); replicas never share a chip")
+    start = (replica % groups) * tp
     return jax.make_mesh((1, tp), ("data", "model"),
                          axis_types=(jax.sharding.AxisType.Auto,) * 2,
-                         devices=devices[:tp])
+                         devices=devices[start:start + tp])

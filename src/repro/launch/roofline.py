@@ -1,4 +1,4 @@
-"""Roofline analysis from dry-run artifacts (EXPERIMENTS.md §Roofline).
+"""Roofline analysis from dry-run artifacts.
 
 Per (arch x shape x mesh) cell, derive from the compiled program:
     compute   T_c = HLO_FLOPs_per_device / peak_FLOPs      [s]
@@ -19,8 +19,28 @@ from __future__ import annotations
 
 from ..configs.base import SHAPES, get_config
 
-# TPU v5e (per chip)
-CHIP = dict(peak_flops=197e12, hbm_bw=819e9, ici_bw=50e9)
+#: Published per-chip peaks keyed by `jax.Device.device_kind` (source:
+#: Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 393 TOP/s
+#: int8, 819 GB/s HBM; ici_bw is the per-link figure the collective term
+#: divides by). A kind missing here is an error, never a default.
+PEAKS = {
+    "TPU v5 lite": dict(peak_flops=197e12, int8_ops=393e12, hbm_bw=819e9,
+                        ici_bw=50e9),
+}
+
+#: The chip the dry-run meshes (a v5e pod slice) are compiled for.
+DRYRUN_DEVICE_KIND = "TPU v5 lite"
+
+
+def chip_peaks(device_kind: str) -> dict:
+    """Peaks of one chip of `device_kind`; ValueError for a kind with no
+    published entry."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r} "
+            f"(known: {sorted(PEAKS)})") from None
 
 
 def param_count(cfg, active_only: bool = False) -> float:
@@ -73,9 +93,10 @@ def analyse_record(rec: dict):
         return None
     cfg = get_config(rec["arch"])
     chips = rec["n_chips"]
-    t_c = rec["flops_per_device"] / CHIP["peak_flops"]
-    t_m = rec["bytes_per_device"] / CHIP["hbm_bw"]
-    t_x = rec["collective_bytes_per_device"] / CHIP["ici_bw"]
+    chip = chip_peaks(rec["device_kind"])
+    t_c = rec["flops_per_device"] / chip["peak_flops"]
+    t_m = rec["bytes_per_device"] / chip["hbm_bw"]
+    t_x = rec["collective_bytes_per_device"] / chip["ici_bw"]
     terms = {"compute": t_c, "memory": t_m, "collective": t_x}
     bottleneck = max(terms, key=terms.get)
     t_bound = max(terms.values())
@@ -87,7 +108,7 @@ def analyse_record(rec: dict):
         bottleneck=bottleneck, bound_time_us=t_bound * 1e6,
         model_flops=mf,
         useful_ratio=mf / hlo_total if hlo_total else 0.0,
-        mfu_bound=mf / (chips * CHIP["peak_flops"] * t_bound)
+        mfu_bound=mf / (chips * chip["peak_flops"] * t_bound)
         if t_bound else 0.0,
         hbm_gb=rec["memory"]["tpu_peak_estimate"] / 2 ** 30
         if "tpu_peak_estimate" in rec["memory"]

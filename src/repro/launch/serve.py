@@ -2,7 +2,7 @@
 
     PYTHONPATH=src python -m repro.launch.serve --arch qwen2_5_14b --reduced \
         --requests 8 --slots 4 --prompt-len 32 --mixed --gen 16 \
-        --policy flexpe-fxp8 --backend pallas --stream
+        --policy flexpe-fxp8 --backend auto --stream
 
 Builds a `serving.ServingEngine` (scheduler/executor split over the slot
 pool + ragged per-request KV cache), submits `--requests` generation
@@ -82,6 +82,7 @@ from ..models import model as M
 from ..serving import (EngineRouter, Request, SamplingParams, ServingEngine)
 from ..serving.router import ROUTING_POLICIES
 from ..serving.scheduler import POLICIES
+from .compile_cache import enable_compile_cache
 from .mesh import make_tp_mesh
 from .train import policy_from_name
 
@@ -375,4 +376,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

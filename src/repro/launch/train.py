@@ -25,6 +25,7 @@ from ..models import model as M
 from ..optim import adamw
 from ..runtime.trainer import TrainLoopConfig, train_loop
 from . import steps as S
+from .compile_cache import enable_compile_cache
 from .mesh import make_host_mesh, make_production_mesh
 
 
@@ -117,4 +118,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

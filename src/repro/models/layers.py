@@ -31,7 +31,7 @@ from ..core import cordic
 from ..core.activation import default_stages, softmax_lv_stages
 from ..core.backend import resolve as _resolve_backend
 from ..core.fxp import FORMATS, dequantize, quantize
-from ..core.precision import PrecisionPolicy, qmatmul
+from ..core.precision import ATTN_PRECISION, PrecisionPolicy, qmatmul
 
 
 def _dispatch():
@@ -165,7 +165,8 @@ def chunked_attention(q, k, v, *, causal: bool, q_offset=0,
         # scores: [B, qc, H, Skv]
         qh = qblk.reshape(b, qc, kvh, g, hd)
         s = jnp.einsum("bqkgd,bskd->bqkgs", qh.astype(jnp.float32),
-                       kg.astype(jnp.float32)) * scale
+                       kg.astype(jnp.float32),
+                       precision=ATTN_PRECISION) * scale
         s = s.reshape(b, qc, h, skv)
         if causal:
             qpos = qoff[:, None] + idx * qc + jnp.arange(qc)[None, :]
@@ -178,7 +179,8 @@ def chunked_attention(q, k, v, *, causal: bool, q_offset=0,
         p = expf(s - m)                                  # [B,qc,H,Skv]
         denom = jnp.sum(p, axis=-1)                      # [B,qc,H]
         ph = p.reshape(b, qc, kvh, g, skv)
-        o = jnp.einsum("bqkgs,bskd->bqkgd", ph, v.astype(jnp.float32))
+        o = jnp.einsum("bqkgs,bskd->bqkgd", ph, v.astype(jnp.float32),
+                       precision=ATTN_PRECISION)
         o = o.reshape(b, qc, h, hd)
         o = _final_div(o, denom[..., None], skv, policy)
         return carry, o.astype(q.dtype)

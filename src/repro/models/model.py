@@ -21,6 +21,7 @@ path, possibly tied to the LM head) stay float.
 from __future__ import annotations
 
 import functools
+import inspect
 from typing import Optional
 
 import jax
@@ -187,6 +188,26 @@ def _ckpt(fn, remat, remat_policy):
     return jax.checkpoint(fn)
 
 
+def _on_shard_mesh(fn):
+    """Trace `fn` inside its `shard` argument's mesh, set as JAX's
+    abstract-mesh context. The pallas dispatch reads that context
+    (`kernels.dispatch._replicated`): Mosaic kernels cannot be
+    partitioned automatically, so under a multi-device mesh each runs
+    inside `jax.shard_map`. Every model entry point that takes `shard`
+    carries this decorator."""
+    sig = inspect.signature(fn)
+
+    @functools.wraps(fn)
+    def wrapped(*args, **kwargs):
+        shard = sig.bind(*args, **kwargs).arguments.get("shard")
+        if shard is None:
+            return fn(*args, **kwargs)
+        with jax.sharding.use_abstract_mesh(shard.mesh.abstract_mesh):
+            return fn(*args, **kwargs)
+    return wrapped
+
+
+@_on_shard_mesh
 def forward(cfg, params, batch, policy: Optional[PrecisionPolicy] = None,
             shard=None, remat: bool = True, last_only: bool = False,
             remat_policy: str = "full"):
@@ -397,6 +418,7 @@ def copy_pool_blocks(cache, src, dst):
     return out
 
 
+@_on_shard_mesh
 def decode_step(cfg, params, cache, tokens_or_embeds,
                 policy: Optional[PrecisionPolicy] = None, shard=None,
                 n_valid=None, last_only: bool = False):

@@ -116,7 +116,6 @@ def moe_ffn(p, x, cfg, policy: Optional[PrecisionPolicy] = None,
     # shard_map when G divides the dp axes.
     smap = None
     if shard is not None:
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as _P
         dpx = shard.dp_axes
         dp_size = 1
@@ -126,10 +125,10 @@ def moe_ffn(p, x, cfg, policy: Optional[PrecisionPolicy] = None,
             def smap(fn, *args):
                 def spec(r):
                     return _P(dpx, *([None] * (r - 1)))
-                return shard_map(
+                return jax.shard_map(
                     jax.vmap(fn), mesh=shard.mesh,
                     in_specs=tuple(spec(a.ndim) for a in args),
-                    out_specs=spec(3), check_rep=False)(*args)
+                    out_specs=spec(3), check_vma=False)(*args)
 
     if smap is not None:
         xe = smap(_dispatch, slot_flat, xt)                      # [G,E*cap,D]

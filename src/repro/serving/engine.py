@@ -64,7 +64,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core.precision import policy_tier
-from ..launch.mesh import make_host_mesh, make_tp_mesh
+from ..launch.mesh import make_tp_mesh
 from .api import FinishedRequest, Request, RequestOutput, SamplingParams
 from .executor import ModelExecutor
 from .prefix_cache import PrefixCache
@@ -120,15 +120,11 @@ class ServingEngine:
         self.prefill_chunk = prefill_chunk
         self.seed = seed
         self.overlap = overlap
-        if tp is not None:
-            if mesh is not None:
-                raise ValueError("pass either tp or mesh, not both")
-            # explicit tensor-parallel degree: a (1, tp) mesh over exactly
-            # tp devices — tp=1 pins single-device serving even when the
-            # host exposes more (forced multi-device CI)
-            self.mesh = make_tp_mesh(tp)
-        else:
-            self.mesh = mesh if mesh is not None else make_host_mesh()
+        if tp is not None and mesh is not None:
+            raise ValueError("pass either tp or mesh, not both")
+        # a (1, tp) mesh over exactly tp devices; no tp and no mesh is
+        # single-device serving, however many devices the host exposes
+        self.mesh = mesh if mesh is not None else make_tp_mesh(tp or 1)
         if kv_blocks is not None and kv_block_size is None:
             raise ValueError("kv_blocks requires kv_block_size (a pool size "
                              "only makes sense for the paged layout)")

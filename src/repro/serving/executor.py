@@ -22,6 +22,7 @@ against the same (cfg, policy, pool geometry) costs no recompile.
 """
 from __future__ import annotations
 
+import functools
 import math
 from typing import List, Optional
 
@@ -32,13 +33,21 @@ from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from ..launch import steps as S
-from ..launch.mesh import make_host_mesh
+from ..launch.mesh import make_tp_mesh
 from ..models import model as M
 
 #: compiled (prefill, decode+sample, seed) step triples shared across
 #: executor instances, plus ("verify", key, chunk)-keyed chunked verify
 #: steps for speculative decoding
 _STEP_CACHE: dict = {}
+
+#: Every serving step keeps the casts it is written with. XLA on a TPU
+#: otherwise drops a bf16 cast whose result is cast straight back to f32
+#: (excess precision), where the CPU and the Pallas kernels round: the
+#: reference attention's bf16-dequantized K/V stayed f32 on a v5e while
+#: the fused kernel's were rounded, about 1% apart per layer.
+_jit = functools.partial(
+    jax.jit, compiler_options={"xla_allow_excess_precision": False})
 
 
 def _sample_core(vocab: int, logits, keys, temps, topks):
@@ -127,12 +136,12 @@ def _compiled_steps(cfg, policy, mesh, max_slots, alloc, chunk, params,
         # tokens come back replicated (every shard holds the full vocab
         # logits — seeding the next tick needs no cross-shard traffic)
         _STEP_CACHE[key] = (
-            jax.jit(prefill_fn, donate_argnums=(1,),
+            _jit(prefill_fn, donate_argnums=(1,),
                     in_shardings=pf_in, out_shardings=pf_out),
-            jax.jit(decode_sample, donate_argnums=(1, 2),
+            _jit(decode_sample, donate_argnums=(1, 2),
                     in_shardings=(p_shard, c_shard, rep, rep, rep, rep, rep),
                     out_shardings=(rep, rep, dc_out[1])),
-            jax.jit(seed, donate_argnums=(0,),
+            _jit(seed, donate_argnums=(0,),
                     in_shardings=(rep,) * 6, out_shardings=(rep, rep)),
             p_shard, c_shard)
     return _STEP_CACHE[key]
@@ -206,7 +215,7 @@ class ModelExecutor:
         self.cfg = cfg
         self.max_slots = max_slots
         if mesh is None:
-            mesh = make_host_mesh()
+            mesh = make_tp_mesh(1)
         self.mesh = mesh
         self.policy = policy
         self.tp = (int(mesh.shape["model"])
@@ -331,12 +340,15 @@ class ModelExecutor:
                 for a in self.cache["ssm"])
             self._ssm_reset_rows.clear()
             self.h2d_updates += 1
+        # upload COPIES of the mirrors: the CPU client may alias a numpy
+        # buffer instead of copying it, and the mirrors keep mutating
+        # while the step that reads the upload is still in flight
         if self._lengths_dirty:
-            self.cache["lengths"] = jnp.asarray(self._lengths_host)
+            self.cache["lengths"] = jnp.asarray(self._lengths_host.copy())
             self._lengths_dirty = False
             self.h2d_updates += 1
         if self.paged and self._tables_dirty:
-            self.cache["block_tables"] = jnp.asarray(self._tables_host)
+            self.cache["block_tables"] = jnp.asarray(self._tables_host.copy())
             self._tables_dirty = False
             self.h2d_updates += 1
 
@@ -371,6 +383,35 @@ class ModelExecutor:
             jnp.stack(logits_rows), keys, temps, topks)
         return toks
 
+    def peek_logits(self, n_valid: np.ndarray, policy=None, tokens=None):
+        """Logits [B, V*] of the next decode step on the current cache and
+        token buffer, computed without advancing either, under `policy`
+        (default: the executor's own). `tokens` [B] replaces the token
+        buffer as the step's input (to feed two engines the same tokens).
+        Rows with `n_valid == 0` are fed token 0, as in the fused decode
+        step. Each policy compiles one extra serve step: this is for
+        checks (backend-vs-backend logits on identical state), not for
+        the serving loop."""
+        if self.cfg.input_mode != "tokens":
+            raise NotImplementedError("peek_logits feeds token ids")
+        policy = self.policy if policy is None else policy
+        key = ("peek", self.step_cache_key, policy)
+        if key not in _STEP_CACHE:
+            fn, _, _, in_sh, out_sh = S.build_serve_step(
+                self.cfg, self.mesh, policy, batch=self.max_slots,
+                max_len=self.alloc, chunk=1,
+                kv_block_size=self.kv_block_size,
+                kv_blocks=self.num_blocks if self.paged else None,
+                params_spec=jax.eval_shape(lambda: self.params))
+            _STEP_CACHE[key] = _jit(
+                lambda p, c, t, n: fn(p, c, t, n)[0],
+                in_shardings=in_sh, out_shardings=out_sh[0])
+        nv = jnp.asarray(np.asarray(n_valid, np.int32))
+        feed = (self._token_buf if tokens is None
+                else jnp.asarray(np.asarray(tokens, np.int32)))
+        tokens = jnp.where(nv > 0, feed, 0)[:, None]
+        return _STEP_CACHE[key](self.params, self.cache, tokens, nv)
+
     # -- speculative decoding (chunked verify + rollback support) -----------
 
     def ensure_verify_step(self, chunk: int):
@@ -389,7 +430,7 @@ class ModelExecutor:
                 kv_blocks=self.num_blocks if self.paged else None,
                 params_spec=pspec)
             rep = NamedSharding(self.mesh, P())
-            _STEP_CACHE[key] = jax.jit(
+            _STEP_CACHE[key] = _jit(
                 fn, donate_argnums=(1, 4),
                 in_shardings=(p_shard, c_shard, rep, rep, rep),
                 out_shardings=(rep, rep, c_shard))

@@ -86,6 +86,7 @@ import numpy as np
 from ..core.precision import tier_policy as make_tier_policy
 from ..core.qtensor import TieredWeights
 from ..core.tiers import tier_index
+from ..launch.mesh import make_tp_mesh
 from .api import FinishedRequest, Request, RequestOutput
 from .engine import ServingEngine
 from .prefix_cache import PrefixCache
@@ -337,14 +338,22 @@ class EngineRouter:
                     "accelerate")
             self.spec_decode = (draft, verify)
 
-        def spec_coordinator(weights, verify_t):
+        # replica i gets its own group of tp devices (make_tp_mesh's
+        # replica placement); without it every replica would build its
+        # mesh over the host's first devices
+        tp = engine_kw.pop("tp", None) or 1
+
+        def placed(i):
+            return dict(engine_kw, mesh=make_tp_mesh(tp, replica=i))
+
+        def spec_coordinator(weights, verify_t, i):
             d, _ = self.spec_decode
             return SpecDecodeCoordinator(
                 cfg, weights.for_tier(d), weights.for_tier(verify_t),
                 draft_policy=make_tier_policy(d, backend=backend),
                 verify_policy=make_tier_policy(verify_t, backend=backend),
                 k=spec_k, max_slots=max_slots,
-                kv_block_size=kv_block_size, **engine_kw)
+                kv_block_size=kv_block_size, **placed(i))
 
         if tiers is not None:
             if "policy" in engine_kw:
@@ -372,14 +381,14 @@ class EngineRouter:
                     "speculative pair accelerates the verify-tier class")
             self.tiered_weights: Optional[TieredWeights] = weights
             self.engines = [
-                spec_coordinator(weights, t)
+                spec_coordinator(weights, t, i)
                 if self.spec_decode and t == self.spec_decode[1]
                 else ServingEngine(
                     cfg, weights.for_tier(t),
                     policy=make_tier_policy(t, backend=backend),
                     max_slots=max_slots,
-                    kv_block_size=kv_block_size, **engine_kw)
-                for t in tiers]
+                    kv_block_size=kv_block_size, **placed(i))
+                for i, t in enumerate(tiers)]
         else:
             if isinstance(self.routing, Tiered):
                 raise ValueError(
@@ -401,14 +410,15 @@ class EngineRouter:
                             f"TieredWeights (has "
                             f"{list(weights.tier_names)})")
                 self.tiered_weights = weights
-                self.engines = [spec_coordinator(weights, self.spec_decode[1])
-                                for _ in range(engines)]
+                self.engines = [
+                    spec_coordinator(weights, self.spec_decode[1], i)
+                    for i in range(engines)]
             else:
                 self.tiered_weights = None
                 self.engines = [
                     ServingEngine(cfg, params, max_slots=max_slots,
-                                  kv_block_size=kv_block_size, **engine_kw)
-                    for _ in range(engines)]
+                                  kv_block_size=kv_block_size, **placed(i))
+                    for i in range(engines)]
         self.max_slots = max_slots
         # tier class map: ladder tier -> replica indices serving it (all
         # replicas of an untiered homogeneous fleet still land here via

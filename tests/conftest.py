@@ -1,77 +1,7 @@
 import jax
 import pytest
 
-import repro.launch.mesh  # noqa: F401  (installs AxisType compat on JAX 0.4.x)
-
 jax.config.update("jax_enable_x64", False)
-
-
-def _install_hypothesis_shim():
-    """`hypothesis` is an optional test extra (see requirements-dev.txt).
-    When absent, install a tiny deterministic @given shim so the property
-    tests still run (a handful of seeded random examples each) instead of
-    aborting collection."""
-    try:
-        import hypothesis  # noqa: F401
-        return
-    except ImportError:
-        pass
-
-
-    import random
-    import sys
-    import types
-
-    class _Strategy:
-        def __init__(self, draw):
-            self.draw = draw
-
-    def integers(lo, hi):
-        return _Strategy(lambda r: r.randint(lo, hi))
-
-    def floats(lo, hi, **_kw):
-        return _Strategy(lambda r: r.uniform(lo, hi))
-
-    def sampled_from(seq):
-        seq = list(seq)
-        return _Strategy(lambda r: seq[r.randrange(len(seq))])
-
-    def given(*strats):
-        def deco(fn):
-            n = getattr(fn, "_shim_max_examples", 10)
-
-            # bare-signature wrapper: the drawn arguments are supplied here,
-            # so pytest must not mistake them for fixtures
-            def wrapper():
-                r = random.Random(0)
-                for _ in range(min(n, 10)):
-                    fn(*[s.draw(r) for s in strats])
-
-            wrapper.__name__ = fn.__name__
-            wrapper.__doc__ = fn.__doc__
-            wrapper.hypothesis_shim = True
-            return wrapper
-        return deco
-
-    def settings(max_examples=10, **_kw):
-        def deco(fn):
-            fn._shim_max_examples = max_examples
-            return fn
-        return deco
-
-    mod = types.ModuleType("hypothesis")
-    st_mod = types.ModuleType("hypothesis.strategies")
-    st_mod.integers = integers
-    st_mod.floats = floats
-    st_mod.sampled_from = sampled_from
-    mod.given = given
-    mod.settings = settings
-    mod.strategies = st_mod
-    sys.modules["hypothesis"] = mod
-    sys.modules["hypothesis.strategies"] = st_mod
-
-
-_install_hypothesis_shim()
 
 
 @pytest.fixture(scope="session")

@@ -39,10 +39,15 @@ def test_registry_has_all_ops():
 def test_backend_resolution_and_override():
     assert resolve(None) == "reference"
     assert resolve("reference") == "reference"
-    # off-TPU, pallas and auto degrade to interpret mode
-    expect = "pallas" if jax.default_backend() == "tpu" else "pallas-interpret"
-    assert resolve("pallas") == expect
-    assert resolve("auto") == expect
+    # auto picks by platform; pallas off-TPU raises instead of quietly
+    # running interpret mode
+    if jax.default_backend() == "tpu":
+        assert resolve("pallas") == "pallas"
+        assert resolve("auto") == "pallas"
+    else:
+        with pytest.raises(RuntimeError, match="pallas-interpret"):
+            resolve("pallas")
+        assert resolve("auto") == "pallas-interpret"
     with backend_ctx.backend("pallas-interpret"):
         assert resolve("reference") == "pallas-interpret"
     assert resolve("reference") == "reference"

@@ -74,7 +74,6 @@ def test_grad_clipping():
 def test_fxp8_grad_compression_single_device():
     """shard_map psum plumbing (axis size 1 -> compression is identity up
     to int8 quantization error)."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     mesh = jax.make_mesh((1,), ("data",),
                          axis_types=(jax.sharding.AxisType.Auto,))
@@ -83,7 +82,7 @@ def test_fxp8_grad_compression_single_device():
     def f(grads):
         return adamw.compress_grads_fxp8(grads, ("data",))
 
-    out = shard_map(f, mesh=mesh, in_specs=({"w": P()},),
-                    out_specs={"w": P()})(g)
+    out = jax.shard_map(f, mesh=mesh, in_specs=({"w": P()},),
+                        out_specs={"w": P()})(g)
     np.testing.assert_allclose(np.asarray(out["w"]), np.asarray(g["w"]),
                                atol=2.0 / 127)

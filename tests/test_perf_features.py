@@ -6,6 +6,7 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.configs import get_config
 from repro.core import PrecisionPolicy
@@ -105,3 +106,14 @@ def test_remat_policy_dots_runs():
     g = jax.grad(lambda pp: M.loss_fn(cfg, pp, batch,
                                       remat_policy="dots")[0])(p)
     assert np.isfinite(float(jax.tree.leaves(g)[0].sum()))
+
+
+def test_roofline_peaks_keyed_by_device_kind():
+    """The peaks table answers for the chip it lists and refuses any
+    other device kind instead of assuming v5e numbers."""
+    from repro.launch.roofline import chip_peaks
+    v5e = chip_peaks("TPU v5 lite")
+    assert (v5e["peak_flops"], v5e["int8_ops"], v5e["hbm_bw"]) == (
+        197e12, 393e12, 819e9)
+    with pytest.raises(ValueError, match="no published peaks"):
+        chip_peaks("cpu")

@@ -332,3 +332,39 @@ def test_router_stream_single_request():
     assert seen == single[2]
     rest = {o.id: o.tokens for o in router.events() if o.finished}
     assert rest == {0: single[0], 1: single[1]}
+
+
+def test_replica_meshes_take_their_own_devices():
+    """`make_tp_mesh(tp, replica=i)` — how the router places replica i —
+    hands out devices [i*tp, (i+1)*tp) while the host has them, and (CPU
+    devices only) wraps round onto groups already in use past the last
+    whole group."""
+    from repro.launch.mesh import make_tp_mesh
+    devices = jax.devices()
+    for tp in (1, 2):
+        groups = len(devices) // tp
+        if groups == 0:
+            continue
+        for i in range(groups + 1):
+            got = list(make_tp_mesh(tp, replica=i).devices.flat)
+            start = (i % groups) * tp
+            assert got == devices[start:start + tp]
+
+
+def test_replicas_never_share_a_chip(monkeypatch):
+    """On a host of chips (not CPU threads) a fleet larger than the host
+    is refused instead of stacking replicas on chips already in use."""
+    import types
+
+    from repro.launch import mesh as mesh_mod
+    chips = [types.SimpleNamespace(id=i, platform="tpu") for i in range(4)]
+    monkeypatch.setattr(mesh_mod.jax, "devices", lambda: chips)
+    monkeypatch.setattr(mesh_mod.jax, "make_mesh",
+                        lambda shape, axes, axis_types, devices: devices)
+    for tp, replica in ((1, 3), (2, 1), (4, 0)):
+        got = mesh_mod.make_tp_mesh(tp, replica=replica)
+        assert [d.id for d in got] == list(
+            range(replica * tp, (replica + 1) * tp))
+    for tp, replica in ((1, 4), (2, 2), (3, 1), (4, 1)):
+        with pytest.raises(RuntimeError, match="never share a chip"):
+            mesh_mod.make_tp_mesh(tp, replica=replica)

@@ -520,3 +520,45 @@ def test_stats_and_finished_metadata():
     assert st["wasted_decodes"] == 0              # sync mode never overruns
     assert all(f.ttft_s >= 0.0 for f in done)
     assert not eng.has_work()
+
+
+@pytest.mark.parametrize("kv_block_size", [None, 4])
+def test_peek_logits_matches_next_greedy_step(kv_block_size):
+    """`ModelExecutor.peek_logits` reads the next decode step without
+    advancing the cache or token buffer: peeking twice gives the same
+    logits, their argmax is the token the engine then samples greedily,
+    and a reference-policy peek on the same state agrees with the pallas
+    one (quantize-once weights share the exact-integer contract), as
+    does a reference-policy engine's own prefill fed the same first
+    tokens through `tokens=`."""
+    from repro.core.precision import PrecisionPolicy
+    from repro.core.qtensor import quantize_params
+    cfg = get_config("qwen2_5_14b").reduced()
+    pol = PrecisionPolicy.flexpe(8, backend="pallas-interpret")
+    params = quantize_params(_params(cfg), "fxp8")
+    eng = ServingEngine(cfg, params, policy=pol, max_slots=2, max_len=24,
+                        prefill_chunk=8, kv_block_size=kv_block_size)
+    for i in range(2):
+        eng.submit(_req(i, 8, cfg, gen=3))
+    first = {o.id: o.tokens[0] for o in eng.step()}
+    assert sorted(first) == [0, 1]
+    nv = np.ones((2,), np.int32)
+    lg = np.asarray(eng.ex.peek_logits(nv))
+    np.testing.assert_array_equal(lg, np.asarray(eng.ex.peek_logits(nv)))
+    ref = np.asarray(eng.ex.peek_logits(nv, policy=pol.with_backend(
+        "reference")))
+    np.testing.assert_allclose(lg, ref, rtol=1e-5, atol=1e-5)
+    ref_eng = ServingEngine(cfg, params, policy=pol.with_backend(
+        "reference"), max_slots=2, max_len=24, prefill_chunk=8,
+        kv_block_size=kv_block_size)
+    for i in range(2):
+        ref_eng.submit(_req(i, 8, cfg, gen=3))
+    ref_eng.step()
+    feed = [first[s.request.id] for s in ref_eng.sched.slots]
+    ref_all = np.asarray(ref_eng.ex.peek_logits(nv, tokens=feed))
+    np.testing.assert_allclose(lg, ref_all, rtol=1e-5, atol=1e-5)
+    second = {o.id: o.tokens[1] for o in eng.step()}
+    rows = {s.request.id: b for b, s in enumerate(eng.sched.slots)
+            if s is not None}
+    for rid, tok in second.items():
+        assert int(np.argmax(lg[rows[rid], :cfg.vocab])) == tok
