@@ -102,4 +102,5 @@ def cordic_af_pallas(x: jax.Array, af: str, hr_stages: int = 4,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
         interpret=interpret,
+        name="cordic_af",
     )(x)

@@ -46,4 +46,5 @@ def cordic_softmax_pallas(x: jax.Array, hr_stages: int = 4,
         out_specs=pl.BlockSpec((bm, n), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
         interpret=interpret,
+        name="cordic_softmax",
     )(x)

@@ -156,6 +156,7 @@ def fxp_gemm_pallas(x_codes: jax.Array, w_codes: jax.Array,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.int32),
         interpret=interpret,
+        name="fxp_gemm",
     )(x_codes, w_codes)
 
 
@@ -213,5 +214,6 @@ def fxp_gemm_fused_pallas(x_codes: jax.Array, w_codes: jax.Array,
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, bn), acc_dtype)],
         interpret=interpret,
+        name="fxp_gemm",
     )(x_codes, w_codes, scale)
     return _merge_pairs(out, bn) if packed else out

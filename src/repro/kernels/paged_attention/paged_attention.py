@@ -221,7 +221,7 @@ def paged_attention_float_pallas(q, k_pool, v_pool, block_tables, meta, *,
         out_shape=jax.ShapeDtypeStruct((b, h, hd), out_dtype),
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=VMEM_LIMIT_BYTES),
-        interpret=interpret)(*args)
+        interpret=interpret, name="paged_attention")(*args)
     return out
 
 
@@ -255,7 +255,7 @@ def paged_attention_int_pallas(q_codes, q_scale, k_pool, v_pool, k_scale,
         out_shape=jax.ShapeDtypeStruct((b, h, hd), out_dtype),
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=VMEM_LIMIT_BYTES),
-        interpret=interpret)(
+        interpret=interpret, name="paged_attention")(
             block_tables, meta, q_codes, q_scale.astype(jnp.float32),
             k_pool, v_pool, k_scale.astype(jnp.float32),
             v_scale.astype(jnp.float32))

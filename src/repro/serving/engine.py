@@ -51,6 +51,22 @@ Paged KV, copy-on-write prefix caching, per-request sampling/RNG, and
 the coalesced per-tick control-array writes are unchanged in semantics
 from the pre-split engine; see `scheduler.py` / `executor.py` for where
 each now lives.
+
+Each tick is annotated with `jax.profiler.TraceAnnotation` spans, which a
+profiler trace records on the host thread that calls `step()`, on the
+same clock as the device's programs; while no trace is being taken each
+costs about a microsecond. `engine.step` (stat `tick`) holds
+`engine.admit` (`admitted`), `engine.plan` (`prefill_rows`,
+`decode_rows`), `engine.flush` (`uploads`: control arrays written to
+the device), one `engine.prefill` per chunk (`request_id`, `start`,
+`take`), `engine.decode` (`rows`: key folding and the fused decode +
+sample), `engine.seed` (`rows`) and one `engine.drain` per tick drained
+(`tick`, `tokens`). A drain holds `engine.sync`, where the host waits
+for the sampled tokens, and one `engine.first_token` per request whose
+first token it emits (`request_id`; `queued_ms` from submit to
+admission and `prefill_ms` from admission to that token, on the
+scheduler's monotonic clock). Stats are host values only: no span adds
+a device sync.
 """
 from __future__ import annotations
 
@@ -62,6 +78,7 @@ from typing import Any, List, Optional, Union
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..core.precision import policy_tier
 from ..launch.mesh import make_tp_mesh
@@ -269,78 +286,92 @@ class ServingEngine:
         fused decode+sample, prefill-seed sampling) WITHOUT syncing any
         sampled value. Returns False when there was nothing to dispatch."""
         sched, ex = self.sched, self.ex
-        for _, slot in sched.admit(self.tick, ex):
-            slot.key = self._request_key(slot.request)
+        with TraceAnnotation("engine.admit") as span:
+            admitted = sched.admit(self.tick, ex)
+            for _, slot in admitted:
+                slot.key = self._request_key(slot.request)
+            span.set_metadata(admitted=len(admitted))
 
         # plan the whole tick first — prefill chunks and decode rows are
         # known before any dispatch, so block allocation and control-array
         # updates coalesce into one flush
-        occupied = [(b, s) for b, s in enumerate(sched.slots)
-                    if s is not None and not s.done]
-        prefill_plan = []                        # (row, slot, tokens, take)
-        for b, slot in occupied:
-            if slot.prefilling:
-                tokens, take = self._prefill_block(slot)
-                sched.ensure_blocks(b, slot.cache_len + take, ex)
-                prefill_plan.append((b, slot, tokens, take))
-        # decode rows hold a device-seeded token and have host headroom:
-        # length finishes are predicted from the SCHEDULED count, so a
-        # request never gets more than max_new_tokens samples dispatched
-        # even before its latest values drain
-        dec = [(b, s) for b, s in occupied
-               if not s.prefilling
-               and 0 < s.scheduled < s.request.max_new_tokens]
-        for b, s in dec:
-            sched.ensure_blocks(b, s.cache_len + 1, ex)
+        with TraceAnnotation("engine.plan") as span:
+            occupied = [(b, s) for b, s in enumerate(sched.slots)
+                        if s is not None and not s.done]
+            prefill_plan = []                    # (row, slot, tokens, take)
+            for b, slot in occupied:
+                if slot.prefilling:
+                    tokens, take = self._prefill_block(slot)
+                    sched.ensure_blocks(b, slot.cache_len + take, ex)
+                    prefill_plan.append((b, slot, tokens, take))
+            # decode rows hold a device-seeded token and have host
+            # headroom: length finishes are predicted from the SCHEDULED
+            # count, so a request never gets more than max_new_tokens
+            # samples dispatched even before its latest values drain
+            dec = [(b, s) for b, s in occupied
+                   if not s.prefilling
+                   and 0 < s.scheduled < s.request.max_new_tokens]
+            for b, s in dec:
+                sched.ensure_blocks(b, s.cache_len + 1, ex)
+            span.set_metadata(prefill_rows=len(prefill_plan),
+                              decode_rows=len(dec))
         if not prefill_plan and not dec:
             return False
-        ex.flush()
+        with TraceAnnotation("engine.flush") as span:
+            uploads = ex.h2d_updates
+            ex.flush()
+            span.set_metadata(uploads=ex.h2d_updates - uploads)
 
         # 1) chunked prefill, one chunk per prefilling slot (B=1 calls);
         #    the final chunk's last-valid logits seed the first sample
         pf_items, pf_rows, pf_logits = [], [], []
         pf_keys, pf_temps, pf_topks = [], [], []
         for b, slot, tokens, take in prefill_plan:
-            lg = ex.prefill(b, tokens, take)
-            slot.prefill_pos += take
-            slot.cache_len += take
-            self.prefill_tokens_computed += take
-            if not slot.prefilling:
-                pf_items.append((b, slot, slot.scheduled))
-                pf_rows.append(b)
-                pf_logits.append(lg)
-                pf_keys.append(jax.random.fold_in(slot.key, slot.scheduled))
-                pf_temps.append(slot.request.sampling.temperature)
-                pf_topks.append(slot.request.sampling.top_k)
-                slot.scheduled += 1
-            sched.register_prefix_blocks(b)
+            with TraceAnnotation("engine.prefill", request_id=slot.request.id,
+                                 start=slot.prefill_pos, take=take):
+                lg = ex.prefill(b, tokens, take)
+                slot.prefill_pos += take
+                slot.cache_len += take
+                self.prefill_tokens_computed += take
+                if not slot.prefilling:
+                    pf_items.append((b, slot, slot.scheduled))
+                    pf_rows.append(b)
+                    pf_logits.append(lg)
+                    pf_keys.append(jax.random.fold_in(slot.key,
+                                                      slot.scheduled))
+                    pf_temps.append(slot.request.sampling.temperature)
+                    pf_topks.append(slot.request.sampling.top_k)
+                    slot.scheduled += 1
+                sched.register_prefix_blocks(b)
 
         # 2) fused pool decode + sample for device-seeded frontier rows
         dec_items, dec_toks = [], None
         if dec:
-            n_valid = np.zeros((self.max_slots,), np.int32)
-            keys = [_zero_key()] * self.max_slots
-            temps = np.zeros((self.max_slots,), np.float32)
-            topks = np.zeros((self.max_slots,), np.int32)
-            for b, s in dec:
-                n_valid[b] = 1
-                keys[b] = jax.random.fold_in(s.key, s.scheduled)
-                temps[b] = s.request.sampling.temperature
-                topks[b] = s.request.sampling.top_k
-                dec_items.append((b, s, s.scheduled))
-                s.scheduled += 1
-                s.cache_len += 1
-            dec_toks = ex.decode_and_sample(
-                n_valid, jnp.stack(keys), jnp.asarray(temps),
-                jnp.asarray(topks))
+            with TraceAnnotation("engine.decode", rows=len(dec)):
+                n_valid = np.zeros((self.max_slots,), np.int32)
+                keys = [_zero_key()] * self.max_slots
+                temps = np.zeros((self.max_slots,), np.float32)
+                topks = np.zeros((self.max_slots,), np.int32)
+                for b, s in dec:
+                    n_valid[b] = 1
+                    keys[b] = jax.random.fold_in(s.key, s.scheduled)
+                    temps[b] = s.request.sampling.temperature
+                    topks[b] = s.request.sampling.top_k
+                    dec_items.append((b, s, s.scheduled))
+                    s.scheduled += 1
+                    s.cache_len += 1
+                dec_toks = ex.decode_and_sample(
+                    n_valid, jnp.stack(keys), jnp.asarray(temps),
+                    jnp.asarray(topks))
 
         # 3) sample + device-seed rows that finished prefill this tick
         pf_toks = None
         if pf_items:
-            pf_toks = ex.seed_tokens(
-                pf_rows, pf_logits, jnp.stack(pf_keys),
-                jnp.asarray(np.asarray(pf_temps, np.float32)),
-                jnp.asarray(np.asarray(pf_topks, np.int32)))
+            with TraceAnnotation("engine.seed", rows=len(pf_items)):
+                pf_toks = ex.seed_tokens(
+                    pf_rows, pf_logits, jnp.stack(pf_keys),
+                    jnp.asarray(np.asarray(pf_temps, np.float32)),
+                    jnp.asarray(np.asarray(pf_topks, np.int32)))
 
         # length finishes are host-predictable: a slot whose LAST sample
         # was just scheduled releases now (blocks returned, row free for
@@ -369,8 +400,10 @@ class ServingEngine:
         # overlapped steady state (this is what sample_syncs_per_token
         # measures; wall clock would hide it on fast hosts)
         gating = not self._inflight
-        dec = np.asarray(ent.dec_toks) if ent.dec_toks is not None else None
-        pf = np.asarray(ent.pf_toks) if ent.pf_toks is not None else None
+        with TraceAnnotation("engine.sync"):
+            dec = (np.asarray(ent.dec_toks) if ent.dec_toks is not None
+                   else None)
+            pf = np.asarray(ent.pf_toks) if ent.pf_toks is not None else None
         items = [(b, slot, idx, dec[b]) for b, slot, idx in ent.dec]
         items += [(b, slot, idx, pf[i])
                   for i, (b, slot, idx) in enumerate(ent.pf)]
@@ -388,9 +421,15 @@ class ServingEngine:
             slot.generated.append(t)
             emitted += 1
             self.emitted_tokens += 1
+            req = slot.request
             if slot.first_token_time is None:
                 slot.first_token_time = now
-            req = slot.request
+                # a marker span: its stats are the request's two phases
+                with TraceAnnotation(
+                        "engine.first_token", request_id=req.id,
+                        queued_ms=1e3 * (slot.admit_time - slot.submit_time),
+                        prefill_ms=1e3 * (now - slot.admit_time)):
+                    pass
             out = RequestOutput(
                 id=req.id, new_tokens=[t], tokens=list(slot.generated),
                 prompt_len=slot.prompt_len, tick=ent.tick, prompt=req.prompt,
@@ -418,12 +457,17 @@ class ServingEngine:
         whatever drained this step (with overlap the drains describe the
         PREVIOUS tick). Draining the buffer here keeps the documented
         `while eng.has_work(): eng.step()` loop live-lock-free."""
-        events: List[RequestOutput] = list(self._out_buffer)
-        self._out_buffer.clear()
-        dispatched = self._dispatch_tick()
-        depth = 1 if (self.overlap and dispatched) else 0
-        while len(self._inflight) > depth:
-            self._drain_one(events)
+        with TraceAnnotation("engine.step", tick=self.tick):
+            events: List[RequestOutput] = list(self._out_buffer)
+            self._out_buffer.clear()
+            dispatched = self._dispatch_tick()
+            depth = 1 if (self.overlap and dispatched) else 0
+            while len(self._inflight) > depth:
+                with TraceAnnotation("engine.drain",
+                                     tick=self._inflight[0].tick) as span:
+                    n = len(events)
+                    self._drain_one(events)
+                    span.set_metadata(tokens=len(events) - n)
         return events
 
     # -- output streams ------------------------------------------------------

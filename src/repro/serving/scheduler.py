@@ -43,7 +43,8 @@ class SlotState:
         self.done = False                    # finished/aborted: drop drains
         self.released = False                # slot/blocks already returned
         self.admitted_tick = tick
-        self.submit_time = 0.0               # set at admission (see submit)
+        self.submit_time = 0.0               # submit time, copied at admission
+        self.admit_time = 0.0                # monotonic time of admission
         self.cache_len = 0                   # tokens written to the cache
         self.blocks_need = blocks_need       # worst-case paged reservation
         self.blocks: List[int] = []          # pool blocks held (paged mode)
@@ -367,6 +368,7 @@ class Scheduler:
             self.pending.pop(pick)
             slot = SlotState(req, tick, blocks_need=need)
             slot.submit_time, submit_tick = self._submitted.pop(req.id)
+            slot.admit_time = time.monotonic()
             wait = tick - submit_tick
             self.queue_wait_max = max(self.queue_wait_max, wait)
             self._queue_wait_sum += wait

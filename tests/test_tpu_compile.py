@@ -10,6 +10,7 @@ results or times. The topology is described inside a module fixture, and
 every test skips where it cannot be described (no TPU compiler).
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -47,19 +48,24 @@ def one_chip(topo):
 
 @pytest.fixture(scope="module")
 def compile_for(one_chip):
-    """compile_for(fn, *(shape, dtype)) -> compiled program for one v5e
-    chip. The persistent compilation cache is off meanwhile: an entry
-    written for a described chip cannot be read back without one."""
+    """compile_for(fn, *(shape, dtype), kernel=name) -> compiled program
+    for one v5e chip, every Pallas call of which is the HLO instruction
+    named after its kernel (the name a profiler trace shows). The
+    persistent compilation cache is off meanwhile: an entry written for
+    a described chip cannot be read back without one."""
     from jax.experimental.compilation_cache import compilation_cache
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
 
-    def compile_for(fn, *shapes):
+    def compile_for(fn, *shapes, kernel):
         args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
                 for s, d in shapes]
         compiled = jax.jit(fn).lower(*args).compile()
-        assert "tpu_custom_call" in compiled.as_text()
+        calls = re.findall(r"%([\w-]+)\.\d+ = [^\n]*"
+                           r'custom_call_target="tpu_custom_call"',
+                           compiled.as_text())
+        assert calls and set(calls) == {kernel}, calls
         return compiled
 
     yield compile_for
@@ -80,7 +86,7 @@ def test_fxp8_gemm_compiles(compile_for, m, k, n, af):
         return dispatch._matmul_pallas(x, w, pol, af=af)
 
     compile_for(fn, ((m, k), jnp.bfloat16), ((k, n), jnp.int8),
-                ((1, n), jnp.float32))
+                ((1, n), jnp.float32), kernel="fxp_gemm")
 
 
 @pytest.mark.parametrize("m,k,n,af", GEMMS)
@@ -94,7 +100,7 @@ def test_packed_fxp4_gemm_compiles(compile_for, m, k, n, af):
         return dispatch._matmul_pallas(x, w, pol, af=af)
 
     compile_for(fn, ((m, k), jnp.bfloat16), ((k, words), jnp.int32),
-                ((1, n), jnp.float32))
+                ((1, n), jnp.float32), kernel="fxp_gemm")
 
 
 @pytest.mark.parametrize("kv", ["bf16", "fxp8", "fxp8-int-attention"])
@@ -118,14 +124,14 @@ def test_paged_attention_compiles(compile_for, kv):
                 ((nb, BS, HEADS, HD), pool_dt), ((nb, BS, HEADS, HD), pool_dt),
                 (scale, jnp.float32), (scale, jnp.float32),
                 ((SLOTS, MB), jnp.int32), ((SLOTS,), jnp.int32),
-                ((SLOTS, 1), jnp.int32))
+                ((SLOTS, 1), jnp.int32), kernel="paged_attention")
 
 
 @pytest.mark.parametrize("shape", [(SLOTS, FF), (128, FF)])
 def test_cordic_af_compiles(compile_for, shape):
     compile_for(lambda x: cordic_af(x, "silu", precision="fxp8",
                                     interpret=False),
-                (shape, jnp.float32))
+                (shape, jnp.float32), kernel="cordic_af")
 
 
 @pytest.mark.parametrize("shape", [(SLOTS, HEADS, 1, MB * BS),
@@ -133,4 +139,4 @@ def test_cordic_af_compiles(compile_for, shape):
 def test_cordic_softmax_compiles(compile_for, shape):
     compile_for(lambda x: cordic_softmax(x, precision="fxp8",
                                          interpret=False),
-                (shape, jnp.float32))
+                (shape, jnp.float32), kernel="cordic_softmax")
