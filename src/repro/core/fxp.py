@@ -20,12 +20,13 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 __all__ = [
     "FxPFormat", "FXP4", "FXP8", "FXP12", "FXP16", "FXP24", "FXP32",
     "FORMATS", "quantize", "dequantize", "fake_quant", "fake_quant_ste",
     "code_dtype",
-    "dynamic_scale", "round_half_even",
+    "dynamic_scale", "amax_scale", "round_half_even",
 ]
 
 
@@ -87,7 +88,15 @@ def dynamic_scale(x: jax.Array, fmt: FxPFormat, axis=None) -> jax.Array:
     amax = jnp.max(jnp.abs(x)) if axis is None else jnp.max(
         jnp.abs(x), axis=axis, keepdims=True)
     amax = jnp.maximum(amax.astype(jnp.float32), 1e-12)
-    return amax / fmt.qmax
+    return amax_scale(amax, fmt)
+
+
+def amax_scale(amax: jax.Array, fmt: FxPFormat) -> jax.Array:
+    """The scale that maps `amax` to qmax: amax times the f32 reciprocal
+    of qmax. Inside jit XLA turns a division by the constant qmax into
+    exactly this product, so eager and jitted calls (a Pallas kernel
+    body included) give the same scale."""
+    return amax * (np.float32(1) / np.float32(fmt.qmax))
 
 
 def code_dtype(fmt: FxPFormat):

@@ -29,7 +29,7 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 
-from .fxp import FORMATS, FxPFormat, code_dtype, quantize
+from .fxp import FORMATS, FxPFormat, amax_scale, code_dtype, quantize
 from .simd import pack, unpack
 from .tiers import TIERS, tier_index
 
@@ -190,11 +190,11 @@ class TieredWeights:
     `QuantizedTensor` codes at that tier's bit width. The per-leaf
     dynamic-range reduction (`amax` over input channels — the expensive
     scan of the float weight) runs ONCE and is shared: each tier's scale
-    is `amax / qmax(tier)`, exactly what `quantize_params` computes per
-    tier, so `for_tier(t)` is bitwise identical to independent surgery —
-    a replica serving from a TieredWeights view decodes the same tokens
-    as one quantized standalone. The 'bf16' tier serves the float source
-    directly (no copy).
+    is amax over its qmax (`amax_scale`), exactly what `quantize_params`
+    computes per tier, so `for_tier(t)` is bitwise identical to
+    independent surgery — a replica serving from a TieredWeights view
+    decodes the same tokens as one quantized standalone. The 'bf16' tier
+    serves the float source directly (no copy).
 
     Memory model: resident bytes = the float source + one code bank per
     quantized tier (FxP4 nibble-packed, FxP8 int8, FxP16 int16) + a
@@ -234,7 +234,7 @@ class TieredWeights:
             self._views[t] = map_weight_leaves(
                 params, lambda w, _fmt=fmt, _t=t: quantize_tensor(
                     w, _t, per_channel=per_channel,
-                    scale=shared_amax(w) / _fmt.qmax),
+                    scale=amax_scale(shared_amax(w), _fmt)),
                 keys=keys)
 
     def __contains__(self, tier: str) -> bool:

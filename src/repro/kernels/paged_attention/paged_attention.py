@@ -9,7 +9,15 @@ per-position scales) and the per-slot block tables directly: grid
 `(B, MB)` walks each row's table one physical block at a time, the block
 index fed straight from a scalar-prefetched table (vLLM-style), with
 dequantization fused into the load. No contiguous view ever touches HBM;
-each allocated block moves HBM->VMEM exactly once.
+each live block moves HBM->VMEM exactly once.
+
+The walk's cost follows each row's live length, not the table's width.
+Slot j of row b is live while j*bs < kv_valid[b] (the row's valid length,
+scalar-prefetched in `meta[b, 1]`); every position of a later, dead slot
+is masked. A dead slot fetches nothing (its block index repeats the last
+live slot's, slot 0 for a row with no valid position, so the pipeline
+issues no copy) and computes nothing: it stages the masked scores and
+zero values (zero scales on the integer path) a sentinel slot stages.
 
 The walk maintains a flash-style running max in VMEM and stages masked
 scores/values into VMEM scratch; the softmax normalisation and the AV
@@ -27,7 +35,15 @@ MiniCPM-2B's 36 x 64 KV heads) bounds the context length; see ROADMAP.
 Masking is in-kernel: position p = j*bs + offset is valid iff p <= the
 row's query position and p < its valid length; unallocated table entries
 (sentinel NB) zero their staged block, mirroring the zero-fill gather of
-the reference path by construction.
+the reference path by construction. The epilogue still reduces the whole
+MB*bs row, so the softmax window does not depend on the row's length.
+Skipping an allocated block past the valid length is exact only for a
+row with at least one valid position: a masked score still weighs its
+value by exp(-1e30 - max), which is 0 under the exact exp but about
+1.7e-38 under the CORDIC exp, and that contribution lies below one ulp
+of the row's sum. A row with no valid position returns the zero-value
+average whatever its table holds (the engine keeps such rows' tables at
+the sentinel, and discards their output).
 
 Three bodies share the walk:
   * `_float_kernel`   — bf16/f32 pools (no KV quantization).
@@ -69,35 +85,54 @@ def _block_positions(j, bs):
             + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)[0])
 
 
+def _live_blocks(kv_valid, bs):
+    """Table slots a row's valid length reaches, ceil(kv_valid / bs): the
+    walk's live prefix. Every position in a later slot is masked."""
+    return (kv_valid + bs - 1) // bs
+
+
 def _float_body(tbl_ref, meta_ref, q_ref, o_ref, s_scr, v_scr, m_scr, *,
                 mb, bs, nb, kvh, g, hd, exp_fn, div_fn, load_kv):
     """Shared walk/epilogue for the float and dequant variants; `load_kv`
     returns this block's (k, v) as f32 [bs, KV, hd]."""
     b, j = pl.program_id(0), pl.program_id(1)
+    qpos = meta_ref[b, 2]
+    kvv = meta_ref[b, 1]
+    live = j < _live_blocks(kvv, bs)
 
     @pl.when(j == 0)
     def _():
-        m_scr[...] = jnp.full_like(m_scr, -jnp.inf)
+        # -1e30 is every masked score, so the row max comes out the same
+        # whether or not the dead slots take part in it
+        m_scr[...] = jnp.full_like(m_scr, -1e30)
 
-    kf, vf = load_kv()
-    alloc = tbl_ref[b, j] < nb          # sentinel rows stage exact zeros
-    kf = jnp.where(alloc, kf, jnp.zeros_like(kf))
-    vf = jnp.where(alloc, vf, jnp.zeros_like(vf))
+    @pl.when(live)
+    def _():
+        kf, vf = load_kv()
+        alloc = tbl_ref[b, j] < nb      # sentinel rows stage exact zeros
+        kf = jnp.where(alloc, kf, jnp.zeros_like(kf))
+        vf = jnp.where(alloc, vf, jnp.zeros_like(vf))
 
-    scale = 1.0 / (hd ** 0.5)
-    qf = q_ref[0].astype(jnp.float32)                       # [KV, g, hd]
-    s_blk = jnp.einsum("kgd,skd->kgs", qf, kf,
-                       precision=ATTN_PRECISION) * scale    # [KV, g, bs]
+        scale = 1.0 / (hd ** 0.5)
+        qf = q_ref[0].astype(jnp.float32)                   # [KV, g, hd]
+        s_blk = jnp.einsum("kgd,skd->kgs", qf, kf,
+                           precision=ATTN_PRECISION) * scale  # [KV, g, bs]
 
-    pos = _block_positions(j, bs)
-    qpos = meta_ref[b, 2]
-    kvv = meta_ref[b, 1]
-    s_blk = jnp.where((pos <= qpos)[None, None, :], s_blk, -1e30)
-    s_blk = jnp.where((pos < kvv)[None, None, :], s_blk, -1e30)
+        pos = _block_positions(j, bs)
+        s_blk = jnp.where((pos <= qpos)[None, None, :], s_blk, -1e30)
+        s_blk = jnp.where((pos < kvv)[None, None, :], s_blk, -1e30)
 
-    m_scr[...] = jnp.maximum(m_scr[...], jnp.max(s_blk, axis=-1))
-    s_scr[j] = s_blk
-    v_scr[pl.ds(j * bs, bs)] = vf
+        m_scr[...] = jnp.maximum(m_scr[...], jnp.max(s_blk, axis=-1))
+        s_scr[j] = s_blk
+        v_scr[pl.ds(j * bs, bs)] = vf
+
+    @pl.when(jnp.logical_not(live))
+    def _():
+        # a dead slot stages what a sentinel slot stages: masked scores
+        # and zero values, so the epilogue reads nothing stale
+        s_scr[j] = jnp.full(s_scr.shape[1:], -1e30, jnp.float32)
+        v_scr[pl.ds(j * bs, bs)] = jnp.zeros((bs,) + v_scr.shape[1:],
+                                             jnp.float32)
 
     @pl.when(j == mb - 1)
     def _():
@@ -139,19 +174,31 @@ def _int_kernel(tbl_ref, meta_ref, qc_ref, sq_ref, k_ref, v_ref, ks_ref,
     associative, so blockwise accumulation is exact by construction),
     scales staged alongside the codes for the epilogue fold."""
     b, j = pl.program_id(0), pl.program_id(1)
+    live = j < _live_blocks(meta_ref[b, 1], bs)
 
-    alloc = tbl_ref[b, j] < nb
-    kc = jnp.where(alloc, k_ref[0], jnp.zeros((), k_ref.dtype))  # [bs,KV,hd]
-    vc = jnp.where(alloc, v_ref[0], jnp.zeros((), v_ref.dtype))
-    ks = jnp.where(alloc, ks_ref[0][..., 0], 0.0)           # [bs, KV]
-    vs = jnp.where(alloc, vs_ref[0][..., 0], 0.0)
+    @pl.when(live)
+    def _():
+        alloc = tbl_ref[b, j] < nb
+        kc = jnp.where(alloc, k_ref[0], jnp.zeros((), k_ref.dtype))
+        vc = jnp.where(alloc, v_ref[0], jnp.zeros((), v_ref.dtype))
+        ks = jnp.where(alloc, ks_ref[0][..., 0], 0.0)       # [bs, KV]
+        vs = jnp.where(alloc, vs_ref[0][..., 0], 0.0)
 
-    # int8 codes go into the MXU as int8, accumulating exactly in int32
-    s_scr[j] = jnp.einsum("kgd,skd->kgs", qc_ref[0], kc,
-                          preferred_element_type=jnp.int32)
-    ks_scr[pl.ds(j * bs, bs)] = ks
-    vs_scr[pl.ds(j * bs, bs)] = vs
-    v_scr[pl.ds(j * bs, bs)] = vc
+        # int8 codes go into the MXU as int8, accumulating exactly in int32
+        s_scr[j] = jnp.einsum("kgd,skd->kgs", qc_ref[0], kc,
+                              preferred_element_type=jnp.int32)
+        ks_scr[pl.ds(j * bs, bs)] = ks
+        vs_scr[pl.ds(j * bs, bs)] = vs
+        v_scr[pl.ds(j * bs, bs)] = vc
+
+    @pl.when(jnp.logical_not(live))
+    def _():
+        # a dead slot stages what a sentinel slot stages: zero codes and
+        # zero scales (its positions are masked in the epilogue)
+        s_scr[j] = jnp.zeros(s_scr.shape[1:], jnp.int32)
+        ks_scr[pl.ds(j * bs, bs)] = jnp.zeros((bs, kvh), jnp.float32)
+        vs_scr[pl.ds(j * bs, bs)] = jnp.zeros((bs, kvh), jnp.float32)
+        v_scr[pl.ds(j * bs, bs)] = jnp.zeros((bs, kvh, hd), v_scr.dtype)
 
     @pl.when(j == mb - 1)
     def _():
@@ -169,12 +216,16 @@ def _int_kernel(tbl_ref, meta_ref, qc_ref, sq_ref, k_ref, v_ref, ks_ref,
         o_ref[0] = out.reshape(kvh * g, hd).astype(o_ref.dtype)
 
 
-def _grid_spec(b, mb, nb, pool_specs, extra_in_specs, scratch, h, hd):
+def _grid_spec(b, mb, bs, nb, pool_specs, extra_in_specs, scratch, h, hd):
     def pool_index(bb, j, tbl, meta):
         # the block-table walk: physical block id straight from the
-        # scalar-prefetched table; sentinel entries clamp in range (their
-        # staged data is zeroed in-kernel)
-        return (jnp.minimum(tbl[bb, j], nb - 1), 0, 0, 0)
+        # scalar-prefetched table. A dead slot (past the row's valid
+        # length) repeats the last live slot's block, and a row with no
+        # valid position reads slot 0, so the pipeline fetches nothing
+        # new for it; sentinel entries clamp in range (their staged data
+        # is zeroed in-kernel)
+        last = jnp.maximum(_live_blocks(meta[bb, 1], bs) - 1, 0)
+        return (jnp.minimum(tbl[bb, jnp.minimum(j, last)], nb - 1), 0, 0, 0)
 
     in_specs = list(extra_in_specs)
     in_specs += [pl.BlockSpec(ps, pool_index) for ps in pool_specs]
@@ -215,7 +266,8 @@ def paged_attention_float_pallas(q, k_pool, v_pool, block_tables, meta, *,
         kern = functools.partial(_dequant_kernel, **kw)
     else:
         kern = functools.partial(_float_kernel, **kw)
-    grid_spec = _grid_spec(b, mb, nb, pool_specs, [q_spec], scratch, h, hd)
+    grid_spec = _grid_spec(b, mb, bs, nb, pool_specs, [q_spec], scratch,
+                           h, hd)
     out = pl.pallas_call(
         kern, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, hd), out_dtype),
@@ -249,7 +301,7 @@ def paged_attention_int_pallas(q_codes, q_scale, k_pool, v_pool, k_scale,
                pltpu.VMEM((s, kvh), jnp.float32),
                pltpu.VMEM((s, kvh, hd), k_pool.dtype),
                pltpu.VMEM((s, kvh), jnp.float32)]
-    grid_spec = _grid_spec(b, mb, nb, pool_specs, lead, scratch, h, hd)
+    grid_spec = _grid_spec(b, mb, bs, nb, pool_specs, lead, scratch, h, hd)
     out = pl.pallas_call(
         kern, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, hd), out_dtype),

@@ -60,7 +60,9 @@ costs about a microsecond. `engine.step` (stat `tick`) holds
 `decode_rows`), `engine.flush` (`uploads`: control arrays written to
 the device), one `engine.prefill` per chunk (`request_id`, `start`,
 `take`), `engine.decode` (`rows`: key folding and the fused decode +
-sample), `engine.seed` (`rows`) and one `engine.drain` per tick drained
+sample; on a paged cache also `live_blocks`, the table slots the rows'
+valid lengths reach, and `table_blocks`, slots times table width),
+`engine.seed` (`rows`) and one `engine.drain` per tick drained
 (`tick`, `tokens`). A drain holds `engine.sync`, where the host waits
 for the sampled tokens, and one `engine.first_token` per request whose
 first token it emits (`request_id`; `queued_ms` from submit to
@@ -168,6 +170,9 @@ class ServingEngine:
             has_ssm=self.ex.has_ssm, prefix_cache=prefix,
             block_shards=self.ex.pool_shards, tier=self.tier)
 
+        # block-table slots per row, all of which a paged decode walks
+        self._table_width = (self.ex.cache["block_tables"].shape[1]
+                             if self.ex.paged else 0)
         self.tick = 0
         self._inflight: deque = deque()      # dispatched, not yet drained
         self._out_buffer: deque = deque()    # events awaiting a consumer
@@ -347,7 +352,7 @@ class ServingEngine:
         # 2) fused pool decode + sample for device-seeded frontier rows
         dec_items, dec_toks = [], None
         if dec:
-            with TraceAnnotation("engine.decode", rows=len(dec)):
+            with TraceAnnotation("engine.decode", rows=len(dec)) as span:
                 n_valid = np.zeros((self.max_slots,), np.int32)
                 keys = [_zero_key()] * self.max_slots
                 temps = np.zeros((self.max_slots,), np.float32)
@@ -363,6 +368,15 @@ class ServingEngine:
                 dec_toks = ex.decode_and_sample(
                     n_valid, jnp.stack(keys), jnp.asarray(temps),
                     jnp.asarray(topks))
+                if ex.paged:
+                    # the paged decode kernel walks every row's table up
+                    # to its valid length (cache_len after this step)
+                    bs = self.kv_block_size
+                    span.set_metadata(
+                        live_blocks=sum(-(-s.cache_len // bs)
+                                        for s in sched.slots
+                                        if s is not None),
+                        table_blocks=self.max_slots * self._table_width)
 
         # 3) sample + device-seed rows that finished prefill this tick
         pf_toks = None

@@ -8,6 +8,7 @@ import glob
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro.configs import get_config
@@ -122,3 +123,39 @@ def test_engine_spans(model, overlap, tmp_path):
     assert sorted(sp[3]["request_id"] for sp in firsts) == sorted(traced)
     assert all(sp[3]["queued_ms"] >= 0 and sp[3]["prefill_ms"] > 0
                for sp in firsts)
+
+
+def test_decode_span_counts_live_blocks(model, tmp_path):
+    """`engine.decode`'s `live_blocks` is the table slots the walked rows'
+    valid lengths reach, Σ ceil(kv_valid / bs) read here off the device's
+    lengths after each decode, and `table_blocks` is slots x table width,
+    on rows that span 1 to 3 blocks."""
+    cfg, params = model
+    bs, slots = 4, 3
+    eng = ServingEngine(cfg, params, max_slots=slots, max_len=16,
+                        prefill_chunk=4, kv_block_size=bs, overlap=True)
+    walked, per_row = [], set()
+    decode = eng.ex.decode_and_sample
+
+    def counted(*args):
+        toks = decode(*args)
+        blocks = -(-np.asarray(eng.ex.cache["lengths"]) // bs)
+        walked.append(int(blocks.sum()))
+        per_row.update(int(n) for n in blocks)
+        return toks
+
+    eng.ex.decode_and_sample = counted
+    for i, n in enumerate([2, 5, 9]):
+        eng.submit(Request(prompt=list(range(1, n + 1)), max_new_tokens=3,
+                           id=i))
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    with jax.profiler.trace(str(tmp_path), profiler_options=opts):
+        while eng.has_work():
+            eng.step()
+    spans = [sp for sp in _engine_spans(tmp_path)
+             if sp[0] == "engine.decode"]
+    assert [sp[3]["live_blocks"] for sp in spans] == walked
+    width = eng.ex.cache["block_tables"].shape[1]
+    assert all(sp[3]["table_blocks"] == slots * width for sp in spans)
+    assert {1, 2, 3} <= per_row

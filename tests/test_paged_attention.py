@@ -26,24 +26,54 @@ FAMILIES = ("qwen2_5_14b", "mamba2_370m", "zamba2_1p2b", "deepseek_moe_16b")
 # op level: fused kernel vs the gather+masked composition
 # ---------------------------------------------------------------------------
 
-def _pools(quant, seed=0):
-    """Random pools + ragged tables: row 1 spans the whole table, rows 0
-    and 2 SHARE their blocks (prefix sharing), row 3 is idle (all
-    sentinel); tail slots of active rows are unallocated."""
+def _layout(name, nb):
+    """(tables [B, MB], query positions [B], decoded tokens [B]) of one
+    table layout; the sentinel nb marks unallocated slots.
+
+    ragged: row 1 spans the whole table, rows 0 and 2 SHARE their blocks
+        (prefix sharing), row 3 is idle (all sentinel); tail slots of
+        active rows are unallocated.
+    wide: 16 slots a row with 1-3 live (the served cells' live share).
+    boundary: valid lengths that end exactly on a block boundary (rows 0
+        and 2) and mid-block (rows 1 and 3).
+    allocated_tail: rows holding ALLOCATED blocks past their valid
+        length, which every position masks (the CORDIC exp still weighs
+        them by about 1.7e-38).
+    """
+    if name == "ragged":
+        mb, rows = 4, [[3, 1], [0, 2, 5, 7], [3, 1], []]
+        lengths, n_valid = [6, 14, 6, 0], [1, 1, 1, 0]
+    elif name == "wide":
+        mb, rows = 16, [[4], [8, 0], [2, 6, 1], [5, 3]]
+        lengths, n_valid = [2, 7, 9, 5], [1, 1, 1, 1]
+    elif name == "boundary":
+        mb, rows = 6, [[1, 2], [3, 4, 5], [6, 7, 8, 0], [2]]
+        lengths, n_valid = [7, 9, 15, 2], [1, 1, 1, 1]
+    else:
+        mb, rows = 8, [[0, 1, 2, 3, 4, 5], [6, 7, 8], [8, 3], [1, 7, 0]]
+        lengths, n_valid = [5, 3, 4, 0], [1, 1, 0, 1]
+    tables = np.full((len(rows), mb), nb, np.int32)
+    for r, blocks in enumerate(rows):
+        tables[r, :len(blocks)] = blocks
+    return (tables, np.asarray(lengths, np.int32),
+            np.asarray(n_valid, np.int32))
+
+
+LAYOUTS = ("ragged", "wide", "boundary", "allocated_tail")
+
+
+def _pools(quant, layout="ragged", seed=0):
+    """Random pools (9 blocks of 4 positions) + one of the LAYOUTS."""
     rng = np.random.default_rng(seed)
     b, kvh, g, hd = 4, 2, 3, 8
-    nb, bs, mb = 9, 4, 4
+    nb, bs = 9, 4
     q = jnp.asarray(rng.normal(size=(b, 1, kvh * g, hd)).astype(np.float32))
     kf = jnp.asarray(rng.normal(size=(nb, bs, kvh, hd)).astype(np.float32))
     vf = jnp.asarray(rng.normal(size=(nb, bs, kvh, hd)).astype(np.float32))
-    tables = np.full((b, mb), nb, np.int32)
-    tables[0, :2] = [3, 1]
-    tables[1, :4] = [0, 2, 5, 7]
-    tables[2, :2] = [3, 1]
+    tables, lengths, n_valid = _layout(layout, nb)
     tables = jnp.asarray(tables)
-    lengths = jnp.asarray([6, 14, 6, 0], jnp.int32)   # query positions
-    n_valid = jnp.asarray([1, 1, 1, 0], jnp.int32)    # row 3: idle
-    kv_valid = lengths + n_valid
+    lengths = jnp.asarray(lengths)                    # query positions
+    kv_valid = lengths + jnp.asarray(n_valid)
     positions = lengths[:, None]
     if quant:
         fmt = FORMATS["fxp8"]
@@ -87,14 +117,15 @@ CASES = [
 ]
 
 
+@pytest.mark.parametrize("layout", LAYOUTS)
 @pytest.mark.parametrize("backend", BACKENDS)
 @pytest.mark.parametrize("name,quant,int_attn,impl", CASES,
                          ids=[c[0] for c in CASES])
 def test_fused_op_bit_exact_vs_gather_path(backend, name, quant, int_attn,
-                                           impl):
+                                           impl, layout):
     del name
     (q, kc, vc, ks, vs, tables, lengths, kv_valid, positions,
-     fmt) = _pools(quant)
+     fmt) = _pools(quant, layout)
     policy = (None if impl is None else
               PrecisionPolicy.flexpe(8, af_impl=impl,
                                      backend=backend))
@@ -105,10 +136,24 @@ def test_fused_op_bit_exact_vs_gather_path(backend, name, quant, int_attn,
     ref = _gather_path(q, kc, vc, ks, vs, tables, lengths, kv_valid,
                        positions, fmt, int_attn, policy)
     # bit-exact everywhere, idle (0-length) row included: both paths see
-    # the same zero-filled unallocated positions by construction
+    # the same zero-filled unallocated positions by construction, and a
+    # masked allocated block's weight lies below one ulp of the row
     np.testing.assert_array_equal(
         np.asarray(got, np.float32), np.asarray(ref, np.float32))
     assert np.isfinite(np.asarray(got, np.float32)).all()
+
+
+def test_cordic_exp_weighs_masked_scores():
+    """The trap the allocated_tail layout guards: under the CORDIC exp a
+    masked score (-1e30) still weighs its value, by about 1.7e-38, so the
+    walk skipping an allocated block past the valid length is exact only
+    because that weight is below one ulp of the row."""
+    from repro.kernels.paged_attention.ref import _exp_fn
+    pol = PrecisionPolicy.flexpe(8, af_impl="cordic")
+    w = float(_exp_fn(pol)(jnp.float32(-1e30)))
+    assert 0.0 < w < 1e-37
+    assert float(_exp_fn(PrecisionPolicy.flexpe(8, af_impl="exact"))(
+        jnp.float32(-1e30))) == 0.0
 
 
 def test_fused_op_shared_tables_rows_agree():

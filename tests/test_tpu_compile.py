@@ -103,27 +103,37 @@ def test_packed_fxp4_gemm_compiles(compile_for, m, k, n, af):
                 ((1, n), jnp.float32), kernel="fxp_gemm")
 
 
+#: (query heads, KV heads, head dim) of the served configurations
+ATTN_HEADS = {"minicpm_2b": (36, 36, 64), "mistral_nemo_12b": (32, 8, 128)}
+
+
+@pytest.mark.parametrize("heads", ATTN_HEADS)
+@pytest.mark.parametrize("mb", [MB, 136])
 @pytest.mark.parametrize("kv", ["bf16", "fxp8", "fxp8-int-attention"])
-def test_paged_attention_compiles(compile_for, kv):
+def test_paged_attention_compiles(compile_for, kv, mb, heads):
     """Fused paged decode attention over a float pool, an int8 pool
-    dequantized at staging, and fully-integer attention on int8 codes."""
-    nb = SLOTS * MB
+    dequantized at staging, and fully-integer attention on int8 codes, at
+    both served head layouts and at the served table width (136 slots:
+    max_len 2048 plus one 128-token chunk, in blocks of 16), where the
+    staged row is largest."""
+    h, kvh, hd = ATTN_HEADS[heads]
+    nb = SLOTS * mb
     fmt = None if kv == "bf16" else FORMATS["fxp8"]
     int_attn = kv == "fxp8-int-attention"
     pol = PrecisionPolicy.flexpe(8, af_impl="exact" if int_attn else "cordic",
                                  backend="pallas")
     pool_dt = jnp.bfloat16 if fmt is None else jnp.int8
-    scale = (nb, BS, HEADS, 1) if fmt is not None else (1, 1, HEADS, 1)
+    scale = (nb, BS, kvh, 1) if fmt is not None else (1, 1, kvh, 1)
 
     def fn(q, kp, vp, ks, vs, tables, lengths, positions):
         return paged_attention(q, kp, vp, ks, vs, tables, lengths=lengths,
                                kv_valid=lengths + 1, positions=positions,
                                fmt=fmt, int_attention=int_attn, policy=pol)
 
-    compile_for(fn, ((SLOTS, 1, HEADS, HD), jnp.bfloat16),
-                ((nb, BS, HEADS, HD), pool_dt), ((nb, BS, HEADS, HD), pool_dt),
+    compile_for(fn, ((SLOTS, 1, h, hd), jnp.bfloat16),
+                ((nb, BS, kvh, hd), pool_dt), ((nb, BS, kvh, hd), pool_dt),
                 (scale, jnp.float32), (scale, jnp.float32),
-                ((SLOTS, MB), jnp.int32), ((SLOTS,), jnp.int32),
+                ((SLOTS, mb), jnp.int32), ((SLOTS,), jnp.int32),
                 ((SLOTS, 1), jnp.int32), kernel="paged_attention")
 
 
